@@ -16,7 +16,10 @@ blocks entirely below this row's valid_from, or entirely past
 cache_pos, are gated off without reading k/v. Ring caches (slot !=
 position) keep the always-correct content mask only. The online
 rescale self-heals any all-masked block (corr -> 0 once a valid slot
-appears); rows with no attendable slot at all flush zeros.
+appears); rows with no attendable slot at all flush zeros. As in the
+flash kernel, the softmax stats and the position row stay 2-D ((rep, 1)
+and (1, bs)): Mosaic refuses to broadcast a 1-D vector back across a
+tile.
 """
 
 from __future__ import annotations
@@ -57,7 +60,7 @@ def _kernel(cpos_ref, vf_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
         q = q_ref[0, 0].astype(jnp.float32) * scale   # (rep, hd)
         k = k_ref[0, 0].astype(jnp.float32)           # (bs, hd)
         v = v_ref[0, 0].astype(jnp.float32)
-        pos = pos_ref[0]                              # (bs,) stored positions
+        pos = pos_ref[...]                            # (1, bs) stored positions
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (rep, bs)
@@ -66,22 +69,22 @@ def _kernel(cpos_ref, vf_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
         valid = (pos >= vf) & (pos <= cache_pos)
         if window:
             valid &= pos > cache_pos - window
-        s = jnp.where(valid[None, :], s, NEG_INF)
+        s = jnp.where(valid, s, NEG_INF)
 
-        m_prev = m_i[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_i[...]                             # (rep, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_i[...] = l_i[...] * corr + p.sum(axis=1)
-        acc[...] = acc[...] * corr[:, None] + jax.lax.dot_general(
+        l_i[...] = l_i[...] * corr + p.sum(axis=1, keepdims=True)
+        acc[...] = acc[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_i[...] = m_new
 
     @pl.when(t == pl.num_programs(2) - 1)
     def _flush():
         seen = m_i[...] > NEG_INF * 0.5
-        out = acc[...] / jnp.maximum(l_i[...], 1e-30)[:, None]
-        o_ref[0, 0] = jnp.where(seen[:, None], out, 0.0).astype(o_ref.dtype)
+        out = acc[...] / jnp.maximum(l_i[...], 1e-30)
+        o_ref[0, 0] = jnp.where(seen, out, 0.0).astype(o_ref.dtype)
 
 
 def decode_attention(q, k, v, pos, cache_pos, valid_from=None, *,
@@ -123,8 +126,8 @@ def decode_attention(q, k, v, pos, cache_pos, valid_from=None, *,
         out_shape=jax.ShapeDtypeStruct((B, KV, rep, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((rep, hd), jnp.float32),
-            pltpu.VMEM((rep,), jnp.float32),
-            pltpu.VMEM((rep,), jnp.float32),
+            pltpu.VMEM((rep, 1), jnp.float32),
+            pltpu.VMEM((rep, 1), jnp.float32),
         ],
         interpret=interpret,
     )(cpos, vf, qg, k, v, pos.reshape(1, S))

@@ -16,6 +16,11 @@ otherwise renormalize garbage (exp(0) per masked entry). The online
 rescale already self-heals any all-masked *block* (corr -> 0 once a
 valid key appears); the flush guard covers the only case it cannot.
 
+The running max and denominator are held 2-D, (bq, 1), and every
+reduction keeps its axis: Mosaic lays a 1-D (bq,) vector out along the
+lanes, and broadcasting it back across a (bq, bk) tile is a shape cast
+it refuses ("unsupported shape cast").
+
 VMEM budget per step (defaults bq=bk=512, hd<=256, fp32 scratch):
 q (512*256*4) + k/v (2*512*256*4) + acc (512*256*4) ~= 2 MiB << 16 MiB
 v5e VMEM; block dims are multiples of (8,128) MXU/VREG tiling.
@@ -77,12 +82,12 @@ def _kernel(vf_ref, q_ref, k_ref, v_ref, o_ref, acc, m_i, l_i, *,
             mask &= pos_k > pos_q - window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_i[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_i[...]                                 # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_i[...] = l_i[...] * corr + p.sum(axis=1)
-        acc[...] = acc[...] * corr[:, None] + jax.lax.dot_general(
+        l_i[...] = l_i[...] * corr + p.sum(axis=1, keepdims=True)
+        acc[...] = acc[...] * corr + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_i[...] = m_new
@@ -91,8 +96,8 @@ def _kernel(vf_ref, q_ref, k_ref, v_ref, o_ref, acc, m_i, l_i, *,
     def _flush():
         # m_i still at NEG_INF <=> the row never saw an attendable key.
         seen = m_i[...] > NEG_INF * 0.5
-        out = acc[...] / jnp.maximum(l_i[...], 1e-30)[:, None]
-        o_ref[0, 0] = jnp.where(seen[:, None], out, 0.0).astype(o_ref.dtype)
+        out = acc[...] / jnp.maximum(l_i[...], 1e-30)
+        o_ref[0, 0] = jnp.where(seen, out, 0.0).astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, valid_from=None, *, window: int = 0,
@@ -136,8 +141,8 @@ def flash_attention(q, k, v, valid_from=None, *, window: int = 0,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, hd), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
     )(vf, q, k, v)

@@ -1,14 +1,13 @@
 """jit'd wrappers: shape checking, padding to block multiples, and the
 model-facing entry point used when `cfg.attn_impl == "pallas"`.
 
-On this CPU container the kernels run in interpret mode
-(`REPRO_PALLAS_INTERPRET=1`, set by tests); on real TPU the same calls
-compile to Mosaic."""
+Interpret mode follows from the platform alone: on the CPU backend the
+kernels run in the Pallas interpreter, on a TPU the same calls compile
+to Mosaic. Nothing else can switch it."""
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -19,8 +18,7 @@ from repro.kernels.int8_matmul import int8_matmul as _int8mm
 
 
 def _interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1" or \
-        jax.devices()[0].platform == "cpu"
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("window", "softcap", "scale",

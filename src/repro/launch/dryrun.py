@@ -40,6 +40,7 @@ from repro.training.optim import adamw, adafactor, cosine_schedule, \
     mixed_precision
 from repro.training.step import (make_train_step, abstract_train_state,
                                  train_state_logical_axes)
+from repro.utils.config import enable_compile_cache
 
 # TPU v5e hardware model (assignment constants).
 PEAK_FLOPS = 197e12       # bf16 FLOP/s per chip
@@ -314,12 +315,7 @@ def main():
     ap.add_argument("--compute-dtype", default=None)
     args = ap.parse_args()
 
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.abspath(".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
-    except Exception:
-        pass
+    enable_compile_cache()
 
     overrides = {
         "seq_shard": None if args.seq_shard is None else args.seq_shard == "on",

@@ -21,6 +21,7 @@ from repro.serving.batching import Request
 from repro.serving.engine import InferenceEngine
 from repro.serving.network import make_network
 from repro.serving.server import CNNSelectServer, ServedModel
+from repro.utils.config import enable_compile_cache
 
 
 def build_default_zoo():
@@ -54,6 +55,7 @@ def main():
     ap.add_argument("--t-threshold", type=float, default=30.0)
     ap.add_argument("--n-tokens", type=int, default=6)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # Resolve the policy before paying engine-compile time so a bad
     # spec fails immediately.

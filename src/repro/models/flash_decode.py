@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ModelConfig
-from repro.utils import shard_map
 
 
 def flash_decode_sharded(q, k_new, v_new, ck, cv, cpos, cache_pos,
@@ -104,7 +103,7 @@ def flash_decode_sharded(q, k_new, v_new, ck, cv, cpos, cache_pos,
 
     vf = (jnp.zeros((B,), jnp.int32) if valid_from is None
           else jnp.asarray(valid_from, jnp.int32))
-    fn = shard_map(
+    fn = jax.shard_map(
         device_fn,
         mesh=parallel.mesh,
         in_specs=(bspec4, bspec4, bspec4, cspec, cspec, P(tp), P(),

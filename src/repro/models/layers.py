@@ -388,9 +388,14 @@ def attn_block(p, x, cfg: ModelConfig, kind: str, positions,
         k, v, pos_k = ck, cv, cpos
         pos_q = positions
     elif cache is not None:
-        # Prefill from position 0: attend over the freshly computed k/v and
-        # write them into the cache preserving the ring invariant
-        # (position p lives at slot p % S).
+        # Prefill from position 0 into an empty cache: attend over the
+        # freshly computed k/v and write them into the cache preserving
+        # the ring invariant (position p lives at slot p % S). Every slot
+        # is written here, the unused ones as zeros / position -1, and
+        # the incoming buffer is read for its shape only: the TPU
+        # compiler drops the zero fill of a layer-scan-carried cache when
+        # each layer updates only part of its slice, which left the
+        # unwritten slots (and their stored positions) uninitialized.
         S = cache["k"].shape[1]
         kd, vd = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
         pd = positions.astype(cache["pos"].dtype)
@@ -400,9 +405,9 @@ def attn_block(p, x, cfg: ModelConfig, kind: str, positions,
             cv = cache["v"].at[:, slots].set(vd[:, T - S:])
             cpos = cache["pos"].at[slots].set(pd[T - S:])
         else:
-            ck = jax.lax.dynamic_update_slice(cache["k"], kd, (0, 0, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cache["v"], vd, (0, 0, 0, 0))
-            cpos = jax.lax.dynamic_update_slice(cache["pos"], pd, (0,))
+            pad = ((0, 0), (0, S - T), (0, 0), (0, 0))
+            ck, cv = jnp.pad(kd, pad), jnp.pad(vd, pad)
+            cpos = jnp.pad(pd, (0, S - T), constant_values=-1)
         new_cache = {"k": ck, "v": cv, "pos": cpos}
         pos_q = pos_k = positions
     else:

@@ -33,7 +33,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ModelConfig
 from repro.models.layers import act_fn
-from repro.utils import shard_map
 
 
 def router_topk(p, x2d, cfg: ModelConfig):
@@ -191,7 +190,7 @@ def moe_ffn_sharded(p, x, cfg: ModelConfig, parallel):
         aux = jax.lax.pmean(aux, data_axes)
         return out, aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         device_fn,
         mesh=parallel.mesh,
         in_specs=(rspec, wspec_in, wspec_in, wspec_out, bspec),

@@ -134,7 +134,8 @@ def init_params(cfg: ModelConfig, key) -> dict:
     dtype = dtype_of(cfg.param_dtype)
     counter = [0]
 
-    def draw(shape, init):
+    def draw(shape, init, n_stacked=0):
+        # n_stacked leading ("layers",) dims are not fan dims.
         counter[0] += 1
         k = jax.random.fold_in(key, counter[0])
         if init == "zeros":
@@ -161,19 +162,20 @@ def init_params(cfg: ModelConfig, key) -> dict:
             fan = shape[-1]
             return (jax.random.normal(k, shape) / np.sqrt(fan)).astype(dtype)
         # fan_in variants: scale by 1/sqrt(prod of input dims).
+        one = shape[n_stacked:]
         if init == "fan_in3":
-            fan = shape[1]
+            fan = one[1]
         elif init == "fan_io":
-            fan = shape[0] * shape[1]
+            fan = one[0] * one[1]
         else:
-            fan = shape[0]
+            fan = one[0]
         return (jax.random.normal(k, shape) / np.sqrt(fan)).astype(dtype)
 
     def mk(shape, axes, init):
         return draw(shape, init)
 
     def mk_stacked(shape, axes, init, n):
-        return draw((n,) + shape, init)
+        return draw((n,) + shape, init, n_stacked=1)
 
     return model_tree(cfg, mk, mk_stacked)
 

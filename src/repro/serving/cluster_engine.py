@@ -10,7 +10,7 @@ per-request loop into three phases:
    per-device state with no cross-device coupling, so it runs as the
    existing scan_engine (L, D) column program — `ctrl_desc_from_
    controller` + `_pack_columns` + `_run_program`, sharded across host
-   devices via `repro.utils.shard_map` exactly like the single-stack
+   devices via `jax.shard_map` exactly like the single-stack
    engine. Output: each request's governing mode and the chronological
    switch-event list (the scale-up/down triggers).
 
@@ -622,7 +622,7 @@ def scan_cluster_run(cluster: Cluster, workload, *, shards: int = 1,
     has_budget = budget is not None
 
     # -- phase 3: the cluster scan ------------------------------------
-    from jax.experimental import enable_x64
+    import jax
     xs = dict(arr=work.arrival, ti=work.t_input, slac=work.t_sla_c,
               slar=work.t_sla_r, has=work.has_sla, prio=work.prio,
               od=work.od, degr=degr, al=alarm, sel=draws.sel,
@@ -641,7 +641,7 @@ def scan_cluster_run(cluster: Cluster, workload, *, shards: int = 1,
             np.int32(cluster.n_active),
             np.zeros(R, np.int32), np.zeros(R, np.int32))
     fn = _compile(R, K, has_budget)
-    with enable_x64():
+    with jax.enable_x64(True):
         carry, ys = fn(xs, init, const)
         free_end, hot_end, last_end, _, n_act_end, up_end, zp_end = (
             np.asarray(v) for v in carry)

@@ -34,7 +34,7 @@ re-implemented.
 
 **Sharding.** All ops are elementwise across the device axis, so the
 fleet shards trivially: `shards=S` pads D to a multiple of S and wraps
-the program in `repro.utils.shard_map` over an S-device mesh — bitwise
+the program in `jax.shard_map` over an S-device mesh — bitwise
 identical to the unsharded run. CPU CI gets its mesh from
 `repro.utils.config.configure(host_devices=N)`.
 """
@@ -544,7 +544,6 @@ def _compile(static_desc, ctrl_desc, shards: int):
 
     if shards > 1:
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.utils import shard_map
         devs = jax.devices()
         if len(devs) < shards:
             raise ValueError(
@@ -553,10 +552,10 @@ def _compile(static_desc, ctrl_desc, shards: int):
                 f"{shards}) before jax initializes (CI sets "
                 f"REPRO_HOST_DEVICES)")
         mesh = Mesh(np.array(devs[:shards]), ("fleet",))
-        run = shard_map(run, mesh=mesh,
-                        in_specs=(P(None, "fleet"), P(None, "fleet"),
-                                  P("fleet")),
-                        out_specs=P(None, "fleet"))
+        run = jax.shard_map(run, mesh=mesh,
+                            in_specs=(P(None, "fleet"), P(None, "fleet"),
+                                      P("fleet")),
+                            out_specs=P(None, "fleet"))
     fn = jax.jit(run)
     _COMPILED[key] = fn
     return fn
@@ -566,7 +565,7 @@ def _run_program(static_desc, ctrl_desc, packed: _Packed,
                  priors_vec: np.ndarray, shards: int):
     """Pad to the shard grid, run the jitted program under x64, strip
     the padding, and hand back numpy arrays."""
-    from jax.experimental import enable_x64
+    import jax
     t_mat, valid = packed.t_mat, packed.valid
     D = t_mat.shape[1]
     pad = (-D) % shards
@@ -580,7 +579,7 @@ def _run_program(static_desc, ctrl_desc, packed: _Packed,
                 priors_vec).any():
             raise ValueError("mean estimator needs a prior")
     fn = _compile(static_desc, ctrl_desc, shards)
-    with enable_x64():
+    with jax.enable_x64(True):
         out = fn(t_mat, valid, np.asarray(priors_vec, np.float64))
         out = {k: np.asarray(v)[:, :D] if pad else np.asarray(v)
                for k, v in out.items()}
@@ -708,9 +707,9 @@ def scan_event_phase(cfg, plan, t_inputs, arrivals, exec_samples,
         lat = (t_inputs + exec_t) + t_inputs   # python's add order
         queue = None
     else:
+        import jax
         import jax.numpy as jnp
         from jax import lax
-        from jax.experimental import enable_x64
         hedgeable = cfg.n_servers > 1
 
         def step(carry, row):
@@ -723,7 +722,7 @@ def scan_event_phase(cfg, plan, t_inputs, arrivals, exec_samples,
             sf = jnp.where(active, sf.at[s].set(start + e), sf)
             return (sf, h + do_h), jnp.where(active, start - a, 0.0)
 
-        with enable_x64():
+        with jax.enable_x64(True):
             (_, hedges), queue = lax.scan(
                 step, (jnp.zeros(cfg.n_servers), jnp.int64(0)),
                 (jnp.asarray(arrivals + t_inputs), jnp.asarray(exec_t),

@@ -113,7 +113,7 @@ class SimConfig:
     # instances) and no memory budget.
     engine: str = "python"
     # Shard the device axis of the scan program across this many jax
-    # devices (repro.utils.shard_map; bitwise identical to shards=1).
+    # devices (jax.shard_map; bitwise identical to shards=1).
     # CPU runs get a mesh via repro.utils.config.configure(
     # host_devices=N) before jax initializes.
     shards: int = 1

@@ -21,18 +21,6 @@ def dtype_of(name: str):
     return DTYPES[name]
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """`jax.shard_map` across jax versions: older releases expose it as
-    `jax.experimental.shard_map.shard_map` with the replication check
-    named `check_rep` instead of `check_vma` (same meaning)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def dtype_bytes(dtype) -> int:
     return jnp.dtype(dtype).itemsize
 

@@ -26,24 +26,21 @@ import os
 import sys
 from typing import Optional
 
-__all__ = ["configure", "jax_is_initialized", "host_device_count"]
+__all__ = ["configure", "enable_compile_cache", "jax_is_initialized",
+           "host_device_count"]
 
 _DEVICE_FLAG = "--xla_force_host_platform_device_count"
+# The checkout root (src/repro/utils/config.py -> three levels up).
+_CHECKOUT = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", ".."))
 
 
 def jax_is_initialized() -> bool:
     """True if jax has already created a backend (config is frozen)."""
     if "jax" not in sys.modules:
         return False
-    try:
-        from jax._src import xla_bridge
-        return xla_bridge.backends_are_initialized()
-    except Exception:  # pragma: no cover - very old/new jax layouts
-        jax = sys.modules["jax"]
-        try:
-            return bool(getattr(jax.lib.xla_bridge, "_backends", None))
-        except Exception:
-            return False
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()
 
 
 def host_device_count() -> Optional[int]:
@@ -78,7 +75,7 @@ def configure(platform: Optional[str] = None,
         process cannot silently fall back to a different backend.
     x64:
         Flip the *global* default float width.  Prefer the scoped
-        ``jax.experimental.enable_x64()`` context inside library code
+        ``jax.enable_x64(True)`` context inside library code
         (the scan engine does exactly that); the global switch is for
         benchmark / CLI entry points that own the whole process.
     host_devices:
@@ -119,3 +116,21 @@ def configure(platform: Optional[str] = None,
         jax.config.update("jax_platform_name", platform)
     if x64 is not None:
         jax.config.update("jax_enable_x64", bool(x64))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here. Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache``, so a second run in the same checkout
+    finds what the first compiled (a path derived from a temp name, pid
+    or time would start empty every run). Call it before the first
+    compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

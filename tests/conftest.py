@@ -6,7 +6,6 @@ import sys
 # fake multi-device CPU topology via REPRO_HOST_DEVICES=N so the
 # shard_map engine path is exercised on plain runners.
 os.environ.pop("XLA_FLAGS", None)
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 

@@ -84,12 +84,24 @@ def _describe(name: str, obj) -> list:
     return [f"{name} [{type(obj).__name__}]"]
 
 
+def _ours(obj) -> bool:
+    """Defined in this package. Names re-exported from jax, typing or
+    the stdlib (`Mesh`, `P`, `dataclass`, ...) carry their own module
+    and are not snapshotted: their signatures follow the installed
+    versions, not this repo. Plain data values (`ARCH_IDS`, `DTYPES`)
+    have no module of their own and stay."""
+    mod = getattr(obj, "__module__", None)
+    return not isinstance(mod, str) or mod.startswith("repro")
+
+
 def _exports(mod) -> list:
     if hasattr(mod, "__all__"):
-        return sorted(mod.__all__)
-    return sorted(n for n, v in vars(mod).items()
-                  if not n.startswith("_") and not inspect.ismodule(v)
-                  and n != "annotations")   # __future__ import leak
+        names = mod.__all__
+    else:
+        names = [n for n, v in vars(mod).items()
+                 if not n.startswith("_") and not inspect.ismodule(v)
+                 and n != "annotations"]   # __future__ import leak
+    return sorted(n for n in names if _ours(getattr(mod, n)))
 
 
 def build_surface() -> str:

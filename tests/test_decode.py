@@ -75,3 +75,27 @@ def test_embeddings_input_decode():
     logits, _ = decode_step(params, x[:, T - 1:], cache, jnp.int32(T - 1), cfg)
     np.testing.assert_allclose(np.asarray(logits[:, 0]),
                                np.asarray(full[:, -1]), atol=5e-3, rtol=1e-3)
+
+
+@pytest.mark.parametrize("arch", ["stablelm_1_6b", "gemma2_9b"])
+def test_prefill_cache_unused_slots_are_empty(arch):
+    """Slots past the prompt hold zeros at stored position -1 after a
+    prefill. Decode masks them by position, and its attention multiplies
+    their (zero-weight) values, so a slot left uninitialized would feed
+    stray positions or NaN into every later step."""
+    cfg = reduced_config(arch)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    T, S = 5, 32
+    x = jax.random.randint(jax.random.PRNGKey(1), (2, T), 0, cfg.vocab)
+    _, cache = prefill(params, x, cfg, max_seq=S)
+    layers = list(cache["blocks"]) + list(cache["tail"])
+    for c in layers:
+        if "pos" not in c:
+            continue
+        n = c["pos"].shape[-1]
+        pos = np.asarray(c["pos"]).reshape(-1, n)
+        assert (pos[:, :T] == np.arange(T)).all()
+        if n > T:
+            assert (pos[:, T:] == -1).all()
+            for key in ("k", "v"):
+                assert not np.asarray(c[key][..., T:, :, :]).any()

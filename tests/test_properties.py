@@ -351,6 +351,8 @@ def test_scan_engine_matches_python_engine(seed, n_devices, estimator,
     from repro.serving.fleet import ArrayFleet
     from repro.serving.simulator import SimConfig, simulate
 
+    if estimator == "observed" and not controller:
+        lag = 0     # EstimatorBank rejects "observed" under a lag
     kw = ({"controller": "reactive", "estimator_lag": lag}
           if controller else
           {"t_estimator": estimator, "estimator_lag": lag})

@@ -9,7 +9,9 @@ here must be intentional and called out in CHANGES.md.
 
 Numbers are exact for numpy-driven policies; cnnselect additionally
 pins the jax threefry/Gumbel stream, so a jax upgrade that changes RNG
-semantics will (by design) trip these tests.
+semantics will (by design) trip these tests. The cnnselect rows and
+the 10k pin follow the partitionable threefry stream
+(`jax_threefry_partitionable`, on by default in JAX 0.9).
 """
 
 import numpy as np
@@ -24,7 +26,8 @@ SEED = 7
 
 # (network, policy) -> (attainment, accuracy, mean_latency)
 GOLDEN = {
-    ("campus_wifi", "cnnselect"): (1.0, 0.815535, 225.61006766393766),
+    ("campus_wifi", "cnnselect"): (1.0, 0.8156574999999999,
+                                   225.83746130757444),
     ("campus_wifi", "greedy"): (0.9675, 0.826, 233.83041029297434),
     ("campus_wifi", "greedy_nw"): (0.995, 0.82514, 232.85234511588246),
     ("campus_wifi", "random"): (1.0, 0.68475, 172.61296963778324),
@@ -32,7 +35,7 @@ GOLDEN = {
         (1.0, 0.718, 149.76329972073734),
     ("campus_wifi", "oracle"): (1.0, 0.8250774999999999,
                                 232.74105129718745),
-    ("lte", "cnnselect"): (0.92, 0.72139, 252.3159290445964),
+    ("lte", "cnnselect"): (0.92, 0.72182, 252.3465206504882),
     ("lte", "greedy"): (0.6275, 0.826, 293.4034219661994),
     ("lte", "greedy_nw"): (0.895, 0.7849249999999998, 272.0746307820539),
     ("lte", "random"): (0.855, 0.68475, 232.18598131100833),
@@ -138,5 +141,5 @@ def test_10k_run_statistics_pinned():
     r = simulate(paper_profiles(), SimConfig(
         t_sla=SLA_MS, n_requests=10000, seed=0))
     assert r.attainment == pytest.approx(0.9988, abs=1e-12)
-    assert r.accuracy == pytest.approx(0.8093139000000001, abs=1e-12)
-    assert r.mean_latency == pytest.approx(228.15808780923885, abs=1e-9)
+    assert r.accuracy == pytest.approx(0.8092194000000001, abs=1e-12)
+    assert r.mean_latency == pytest.approx(228.1411196893983, abs=1e-9)
