@@ -12,11 +12,11 @@ storage budget and should hold a frontier slot over its fp32 peers.
 
 The perf matrix re-runs each zoo row under every attention impl and
 reports tokens/s, prefill_ms and the live resident bytes (int8 engines
-hold (int8, scale) trees). ``--full`` appends the matrix to
-``benchmarks/results/BENCH_measured_serving.json`` as a trajectory
-point. NOTE: on CPU the pallas kernels run in *interpret mode* — the
-matrix measures dispatch/masking correctness-at-speed there, while the
-Mosaic-compiled ratios only mean anything on real TPU.
+hold (int8, scale) trees); ``--full`` adds the capacity rows. NOTE: on
+CPU the pallas kernels run in *interpret mode* — the matrix measures
+dispatch/masking correctness-at-speed there, while the Mosaic-compiled
+ratios only mean anything on real TPU, where `bench/` measures the
+served path.
 
 Smoke (CI fast job): ``python benchmarks/measured_serving.py --smoke``.
 Full (acceptance): ``python benchmarks/measured_serving.py --full``."""
@@ -24,13 +24,10 @@ Full (acceptance): ``python benchmarks/measured_serving.py --full``."""
 from __future__ import annotations
 
 import argparse
-import json
-import os
-import time
 
 import numpy as np
 
-from benchmarks.common import RESULTS_DIR, emit, row
+from benchmarks.common import emit, row
 
 N_REQUESTS = 48
 SEED = 11
@@ -127,14 +124,14 @@ IMPLS = ("naive", "pallas")
 def perf_matrix(names=None, *, batch_size: int = 4, max_seq: int = 64,
                 prompt_len: int = 16, n_tokens: int = 8, reps: int = 3,
                 impls=IMPLS):
-    """(rows, points): every requested zoo row × attention impl, timed
-    on this host. Each point carries tokens/s, prefill_ms, per_token_ms
-    and the engine's live resident bytes; per-model speedup rows compare
-    the pallas fast path against the naive reference."""
+    """Rows of every requested zoo row × attention impl, timed on this
+    host: tokens/s, prefill_ms and the engine's live resident bytes;
+    per-model speedup rows compare the pallas fast path against the
+    naive reference."""
     from repro.configs.paper_zoo import MEASURED_ZOO, measured_zoo_names
     from repro.serving.measured import build_model
 
-    rows, points = [], []
+    rows = []
     for i, name in enumerate(measured_zoo_names(names)):
         per = {}
         for impl in impls:
@@ -152,18 +149,10 @@ def perf_matrix(names=None, *, batch_size: int = 4, max_seq: int = 64,
                                     f"{p['resident_bytes'] / 1e6:.2f}",
                                 "int8": MEASURED_ZOO[name]["quant"] == "int8",
                             }))
-            points.append({
-                "model": name, "impl": impl,
-                "tokens_s": round(toks_s, 1),
-                "prefill_ms": round(p["prefill_ms"], 3),
-                "per_token_ms": round(p["per_token_ms"], 4),
-                "resident_bytes": int(p["resident_bytes"]),
-                "int8": MEASURED_ZOO[name]["quant"] == "int8",
-            })
         if "naive" in per and "pallas" in per:
             rows.append(row(f"measured.perf.{name}.speedup", 0.0, {
                 "pallas_vs_naive": f"{per['pallas'] / per['naive']:.2f}x"}))
-    return rows, points
+    return rows
 
 
 def main():
@@ -171,32 +160,18 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="one tiny model, 1 rep (CI fast-job smoke)")
     ap.add_argument("--full", action="store_true",
-                    help="full zoo matrix + capacity rows, and append "
-                         "the BENCH_*.json trajectory point")
+                    help="full zoo matrix + capacity rows")
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=64)
     args = ap.parse_args()
     print("name,us_per_call,derived")
     if args.smoke:
-        rows, _ = perf_matrix(["lm_tiny"], batch_size=2, max_seq=32,
+        rows = perf_matrix(["lm_tiny"], batch_size=2, max_seq=32,
                               prompt_len=8, n_tokens=2, reps=1)
         emit(rows)
         return
-    rows, points = perf_matrix(batch_size=args.batch_size,
-                               max_seq=args.max_seq)
+    rows = perf_matrix(batch_size=args.batch_size, max_seq=args.max_seq)
     if args.full:
-        path = os.path.join(RESULTS_DIR, "BENCH_measured_serving.json")
-        os.makedirs(RESULTS_DIR, exist_ok=True)
-        series = []
-        if os.path.exists(path):
-            series = json.load(open(path)).get("series", [])
-        series.append({"unix_time": int(time.time()),
-                       "batch_size": args.batch_size,
-                       "max_seq": args.max_seq, "points": points})
-        with open(path, "w") as f:
-            json.dump({"bench": "measured_serving", "series": series}, f,
-                      indent=2, sort_keys=True)
-        rows.append(row("measured.perf.trajectory", 0.0, {"path": path}))
         rows += run()
     emit(rows)
 

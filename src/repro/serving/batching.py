@@ -9,6 +9,7 @@ the scheduler's job is slot assignment, padding, and retirement."""
 from __future__ import annotations
 
 import heapq
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, List, Optional
@@ -36,6 +37,13 @@ class Request:
     start_exec: float = field(compare=False, default=0.0)
     finish: float = field(compare=False, default=0.0)
     model: str = field(compare=False, default="")
+    # Wall-clock stamps (time.perf_counter() s) set by the batcher:
+    # queued, given a slot, first token, retired. `start_exec`/`finish`
+    # above are the loop's per-drain virtual clock, which `run` replays.
+    wall_queued: Optional[float] = field(compare=False, default=None)
+    wall_start: Optional[float] = field(compare=False, default=None)
+    wall_first: Optional[float] = field(compare=False, default=None)
+    wall_finish: Optional[float] = field(compare=False, default=None)
 
 
 class FifoQueue:
@@ -76,6 +84,7 @@ class ContinuousBatcher:
         self.done: List[Request] = []
 
     def submit(self, req: Request):
+        req.wall_queued = time.perf_counter()
         heapq.heappush(self.queue, req)
 
     @property
@@ -101,9 +110,11 @@ class ContinuousBatcher:
         if not ready:
             return None
         self.slots = [None] * self.batch_size
+        wall = time.perf_counter()
         for i, r in enumerate(ready):
             self.slots[i] = r
             r.start_exec = now
+            r.wall_start = wall
         return ready
 
     def backfill(self, now: float, budget: Optional[int] = None):
@@ -127,6 +138,7 @@ class ContinuousBatcher:
                     continue
                 self.slots[i] = r
                 r.start_exec = now
+                r.wall_start = time.perf_counter()
                 joins.append((i, r))
                 break
             if self.slots[i] is None:
@@ -162,8 +174,12 @@ class ContinuousBatcher:
         if r is None:
             return
         r.tokens.append(int(tok))
+        wall = time.perf_counter()
+        if len(r.tokens) == 1:
+            r.wall_first = wall
         if len(r.tokens) >= r.max_new_tokens:
             r.finish = now
+            r.wall_finish = wall
             self.done.append(r)
             self.slots[slot] = None
 

@@ -16,6 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import decode_step, forward, init_cache
 from repro.models.config import ATTN_KINDS, ModelConfig
@@ -31,6 +32,9 @@ class EngineStats:
     decode_time_s: float = 0.0
     backfill_time_s: float = 0.0
     compile_time_s: float = 0.0
+    # Copying the returned logits to the host, every kind of call; the
+    # *_time_s above stop at the device result and leave it out.
+    fetch_time_s: float = 0.0
 
 
 class InferenceEngine:
@@ -136,6 +140,15 @@ class InferenceEngine:
         self.stats.compile_time_s += dt
         return dt
 
+    def _fetch(self, logits, index) -> np.ndarray:
+        """`logits[index]`, the next-token logits, on the host
+        (`serve.fetch`)."""
+        t0 = time.perf_counter()
+        with TraceAnnotation("serve.fetch"):
+            out = np.asarray(logits[index])
+        self.stats.fetch_time_s += time.perf_counter() - t0
+        return out
+
     def _valid_from_for(self, tokens, lengths):
         """(B,) first attendable absolute position per row, or None."""
         B, T = tokens.shape
@@ -158,16 +171,19 @@ class InferenceEngine:
         masked out of attention so they cannot contaminate logits or
         later cache reads. Returns next-token logits; stores cache."""
         assert tokens.shape[0] == self.batch_size
-        vf = self._valid_from_for(tokens, lengths)
-        t0 = time.perf_counter()
-        logits, cache = self._prefill(self.params, jnp.asarray(tokens), vf)
-        logits.block_until_ready()
+        with TraceAnnotation("serve.launch"):
+            vf = self._valid_from_for(tokens, lengths)
+            t0 = time.perf_counter()
+            logits, cache = self._prefill(self.params, jnp.asarray(tokens),
+                                          vf)
+        with TraceAnnotation("serve.sync"):
+            logits.block_until_ready()
         self.stats.prefill_calls += 1
         self.stats.prefill_time_s += time.perf_counter() - t0
         self.cache = cache
         self.cache_pos = tokens.shape[1]
         self.valid_from = vf
-        return np.asarray(logits[:, 0])
+        return self._fetch(logits, (slice(None), 0))
 
     def run_decode(self, tokens: np.ndarray):
         """tokens: (B, 1) int32 next tokens. Returns logits (B, V)."""
@@ -180,14 +196,16 @@ class InferenceEngine:
                 f"KV cache full (cache_pos={self.cache_pos}, "
                 f"max_seq={self.max_seq})")
         t0 = time.perf_counter()
-        logits, self.cache = self._decode(
-            self.params, jnp.asarray(tokens), self.cache,
-            jnp.int32(self.cache_pos), self.valid_from)
-        logits.block_until_ready()
+        with TraceAnnotation("serve.launch"):
+            logits, self.cache = self._decode(
+                self.params, jnp.asarray(tokens), self.cache,
+                jnp.int32(self.cache_pos), self.valid_from)
+        with TraceAnnotation("serve.sync"):
+            logits.block_until_ready()
         self.cache_pos += 1
         self.stats.decode_calls += 1
         self.stats.decode_time_s += time.perf_counter() - t0
-        return np.asarray(logits[:, 0])
+        return self._fetch(logits, (slice(None), 0))
 
     def prefill_row(self, prompt: np.ndarray, slot: int, length=None):
         """Backfill: prefill one request into batch slot `slot` mid-group.
@@ -218,18 +236,20 @@ class InferenceEngine:
             raise ValueError(f"length must be in [1, {T}]")
         vf_row = self.cache_pos - length
         t0 = time.perf_counter()
-        logits, rcache = self._prefill_row(
-            self.params, jnp.asarray(prompt)[None], jnp.int32(offset),
-            jnp.asarray([vf_row], jnp.int32))
-        self.cache = self._merge(self.cache, rcache, jnp.int32(slot),
-                                 jnp.int32(offset), T)
-        logits.block_until_ready()
+        with TraceAnnotation("serve.launch"):
+            logits, rcache = self._prefill_row(
+                self.params, jnp.asarray(prompt)[None], jnp.int32(offset),
+                jnp.asarray([vf_row], jnp.int32))
+            self.cache = self._merge(self.cache, rcache, jnp.int32(slot),
+                                     jnp.int32(offset), T)
+        with TraceAnnotation("serve.sync"):
+            logits.block_until_ready()
         self.stats.backfill_calls += 1
         self.stats.backfill_time_s += time.perf_counter() - t0
         vf = np.asarray(self.valid_from).copy()
         vf[slot] = vf_row
         self.valid_from = jnp.asarray(vf)
-        return np.asarray(logits[0, 0])
+        return self._fetch(logits, (0, 0))
 
     @property
     def free_context(self) -> int:

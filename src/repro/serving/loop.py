@@ -17,9 +17,11 @@ host's executed latencies."""
 from __future__ import annotations
 
 import time
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.selection import ModelProfile
 from repro.serving.batching import ContinuousBatcher, Request
@@ -28,6 +30,7 @@ from repro.serving.engine import InferenceEngine
 from repro.serving.metrics import ServingMetrics
 from repro.serving.router import Router
 from repro.serving.stack import StackOutcome
+from repro.serving.telemetry import install_gc_span, span
 
 
 class LoopMetrics(ServingMetrics):
@@ -35,6 +38,18 @@ class LoopMetrics(ServingMetrics):
     (serving/metrics.py); kept as a named subclass for imports. The
     pre-unification ``mean_e2e_ms``/``p95_e2e_ms`` summary keys are now
     ``mean_ms``/``p95_ms`` (migration note in CHANGES.md)."""
+
+
+@dataclass
+class LoopStats:
+    """Counters of the loop's own work, summed over every drain (plain
+    numbers, so two snapshots subtract field by field)."""
+    groups: int = 0             # group prefills seeded
+    group_rows: int = 0         # live requests in those prefills
+    queued_at_group: int = 0    # queue length when each was seeded
+    backfill_joins: int = 0     # requests joined mid-group
+    sample_s: float = 0.0       # host argmax over returned logits
+    retire_s: float = 0.0       # recording tokens, finishing requests
 
 
 class ServingLoop:
@@ -100,6 +115,8 @@ class ServingLoop:
                                         seed=seed,
                                         t_threshold=t_threshold)
         self.metrics = LoopMetrics()
+        self.stats = LoopStats()
+        install_gc_span()
         self._req_modes: Dict[int, str] = {}
         # Optional trace capture (serving/trace.py, DESIGN.md §11):
         # `run` records each drained request with its SLA outcome.
@@ -126,6 +143,10 @@ class ServingLoop:
         """Protocol admission: route (through the shared control step
         when a controller is attached) and queue on the chosen model's
         batcher; execution and the metrics row land at `drain`."""
+        with TraceAnnotation("serve.submit"):
+            return self._submit(req, now)
+
+    def _submit(self, req: Request, now: float) -> StackOutcome:
         if self.router is None:
             only = next(iter(self.engines))
             self.batchers[only].submit(req)
@@ -173,8 +194,22 @@ class ServingLoop:
                 sla_ok=(self.metrics.records[-1]["ok"]
                         if r.sla_ms else None))
 
+    def _retire(self, name: str, batcher: ContinuousBatcher,
+                acc: Dict[int, float], n_done: int) -> int:
+        """Finish every request retired since `n_done`; the new count."""
+        while n_done < len(batcher.done):
+            r = batcher.done[n_done]
+            self._finish(r, name, acc.pop(r.rid, 0.0))
+            n_done += 1
+        return n_done
+
     def _drain(self, name: str, batcher: ContinuousBatcher):
+        with TraceAnnotation(span("drain", name)):
+            self._serve_queue(name, batcher)
+
+    def _serve_queue(self, name: str, batcher: ContinuousBatcher):
         eng = self.engines[name]
+        stats = self.stats
         now = 0.0
         # rid -> exec ms accumulated while the request occupied a slot.
         # Every engine call's wall time is charged to the requests that
@@ -189,52 +224,68 @@ class ServingLoop:
                 # seed a fresh group.
                 if not batcher.queue:
                     break
-                now = max(now, batcher.queue[0].arrival)
-                group = batcher.form_group(now)
-                if group is None:
-                    break
+                with TraceAnnotation(span("group", name)):
+                    queued = len(batcher.queue)
+                    now = max(now, batcher.queue[0].arrival)
+                    group = batcher.form_group(now)
+                    if group is None:
+                        break
+                    prompts = batcher.pad_prompts()
+                    lengths = batcher.prompt_lengths()
+                stats.groups += 1
+                stats.group_rows += len(group)
+                stats.queued_at_group += queued
                 t0 = time.perf_counter()
-                logits = eng.run_prefill(batcher.pad_prompts(),
-                                         lengths=batcher.prompt_lengths())
+                with TraceAnnotation(span("prefill", name)):
+                    logits = eng.run_prefill(prompts, lengths=lengths)
                 dt = (time.perf_counter() - t0) * 1000.0
                 now += dt
                 for r in group:
                     acc[r.rid] = dt
             # One aligned decode round: sample, record/retire, backfill
             # freed slots, then step the whole group.
-            nxt = logits.argmax(-1).astype(np.int32)
-            batcher.record_tokens(nxt, now)
-            while n_done < len(batcher.done):
-                r = batcher.done[n_done]
-                self._finish(r, name, acc.pop(r.rid, 0.0))
-                n_done += 1
+            t0 = time.perf_counter()
+            with TraceAnnotation("serve.sample"):
+                nxt = logits.argmax(-1).astype(np.int32)
+            t1 = time.perf_counter()
+            with TraceAnnotation("serve.retire"):
+                batcher.record_tokens(nxt, now)
+                n_done = self._retire(name, batcher, acc, n_done)
+            stats.sample_s += t1 - t0
+            stats.retire_s += time.perf_counter() - t1
             if batcher.n_active == 0:
                 continue            # drained; next iteration reseeds
             if eng._backfillable:
                 for slot, r in batcher.backfill(now, eng.free_context):
+                    stats.backfill_joins += 1
                     prompt = np.zeros(batcher.prompt_len, np.int32)
                     p = r.prompt[-batcher.prompt_len:]
                     prompt[len(prompt) - len(p):] = p
                     t0 = time.perf_counter()
-                    tok = int(eng.prefill_row(prompt, slot, length=len(p))
-                              .argmax(-1))
-                    dt = (time.perf_counter() - t0) * 1000.0
+                    with TraceAnnotation(span("backfill", name)):
+                        row = eng.prefill_row(prompt, slot, length=len(p))
+                    t1 = time.perf_counter()
+                    with TraceAnnotation("serve.sample"):
+                        tok = int(row.argmax(-1))
+                    t2 = time.perf_counter()
+                    stats.sample_s += t2 - t1
+                    dt = (t2 - t0) * 1000.0
                     now += dt
                     # The whole group stalls for the row prefill.
                     for rr in batcher.slots:
                         if rr is not None:
                             acc[rr.rid] = acc.get(rr.rid, 0.0) + dt
                     nxt[slot] = tok
-                    batcher.record_token(slot, tok, now)
-                    while n_done < len(batcher.done):
-                        done_r = batcher.done[n_done]
-                        self._finish(done_r, name,
-                                     acc.pop(done_r.rid, 0.0))
-                        n_done += 1
+                    t0 = time.perf_counter()
+                    with TraceAnnotation("serve.retire"):
+                        batcher.record_token(slot, tok, now)
+                        n_done = self._retire(name, batcher, acc, n_done)
+                    stats.retire_s += time.perf_counter() - t0
             if batcher.n_active == 0:
                 continue
             t0 = time.perf_counter()
-            logits = eng.run_decode(nxt[:, None])
+            with TraceAnnotation(span("decode", name)):
+                logits = eng.run_decode(nxt[:, None])
             dt = (time.perf_counter() - t0) * 1000.0
             now += dt
             for rr in batcher.slots:

@@ -1,5 +1,10 @@
 """Continuous-batching serving loop integration."""
 
+import dataclasses
+import gc
+import glob
+import os
+
 import numpy as np
 import pytest
 
@@ -120,3 +125,124 @@ def test_loop_recorder_captures_run(loop):
     with TraceRecorder().attach(loop) as rec2:
         loop.run(_reqs(2, rng, sla=0.0))
     assert (rec2.to_trace(source="loop").sla_ok == -1).all()
+
+
+# -- Telemetry: wall-clock stamps, loop counters, engine fetch, spans ---
+
+def _req(rid, arrival, max_new, rng):
+    return Request(arrival=arrival, rid=rid,
+                   prompt=rng.integers(0, 50, 6).astype(np.int32),
+                   max_new_tokens=max_new, sla_ms=1e9, t_input_ms=5.0)
+
+
+def _served(loop, reqs):
+    """Submit every request, drain, and return the loop's counters
+    gained meanwhile."""
+    before = dataclasses.replace(loop.stats)
+    for r in reqs:
+        loop.submit(r)
+    loop.drain()
+    return {k: getattr(loop.stats, k) - getattr(before, k)
+            for k in vars(before)}
+
+
+def _stamps_ordered(r):
+    return (r.wall_queued is not None
+            and r.wall_queued <= r.wall_start <= r.wall_first
+            <= r.wall_finish)
+
+
+def test_backfill_schedule_counts_and_stamps(loop):
+    """Batch 2: a one-token and a four-token request seed the group; the
+    first retires at once and the third, queued all along, joins its
+    slot mid-group. Every request's wall stamps come in order."""
+    rng = np.random.default_rng(4)
+    reqs = [_req(900, 0.0, 1, rng), _req(901, 0.0, 4, rng),
+            _req(902, 0.0, 2, rng)]
+    got = _served(loop, reqs)
+    assert got["groups"] == 1 and got["group_rows"] == 2
+    assert got["queued_at_group"] == 3 and got["backfill_joins"] == 1
+    assert got["sample_s"] > 0 and got["retire_s"] > 0
+    assert all(_stamps_ordered(r) for r in reqs)
+    # the joiner got its slot after the group was seeded
+    assert reqs[2].wall_start > reqs[0].wall_start == reqs[1].wall_start
+    assert reqs[0].wall_first == reqs[0].wall_finish
+
+
+def test_first_group_of_a_drain_takes_only_the_earliest(loop):
+    """Two requests queued before the drain, due 0 and 5 ms apart: the
+    drain's clock starts at the first arrival, so the first group seeds
+    one row and leaves the other queued behind it. The counter records
+    this seeding as it is today."""
+    rng = np.random.default_rng(5)
+    reqs = [_req(910, 0.0, 1, rng), _req(911, 5.0, 1, rng)]
+    got = _served(loop, reqs)
+    assert got["groups"] == 2 and got["group_rows"] == 2
+    assert got["queued_at_group"] == 3      # 2 queued, then 1
+    assert got["queued_at_group"] - got["group_rows"] == 1
+    assert got["backfill_joins"] == 0
+    assert all(_stamps_ordered(r) for r in reqs)
+
+
+def test_fetch_time_grows_with_every_engine_call(loop):
+    eng = loop.engines["m"]
+    toks = np.ones((eng.batch_size, 8), np.int32)
+    calls = [lambda: eng.run_prefill(toks),
+             lambda: eng.run_decode(toks[:, :1]),
+             lambda: eng.prefill_row(toks[0], 1)]
+    for call in calls:
+        before = dataclasses.replace(eng.stats)
+        call()
+        assert eng.stats.fetch_time_s > before.fetch_time_s
+        n = (eng.stats.prefill_calls + eng.stats.decode_calls
+             + eng.stats.backfill_calls)
+        assert n == (before.prefill_calls + before.decode_calls
+                     + before.backfill_calls + 1)
+
+
+def test_gc_span_is_installed_once(loop):
+    from repro.serving import telemetry
+    ServingLoop({"m": loop.engines["m"]})
+    assert gc.callbacks.count(telemetry._gc_span) == 1
+
+
+def _host_spans(log_dir):
+    """[(start_ns, end_ns, name)] of every `serve.` host event."""
+    path = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                  "*.xplane.pb"))[0]
+    pd = jax.profiler.ProfileData.from_file(path)
+    return [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("serve.")]
+
+
+def test_spans_of_a_traced_run(loop, tmp_path):
+    """Under the profiler, the loop's phases and the engine's launch,
+    sync and fetch appear as `serve.` host spans, the engine's nested
+    inside the loop's prefill span of the candidate."""
+    rng = np.random.default_rng(6)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _served(loop, [_req(920, 0.0, 1, rng), _req(921, 0.0, 3, rng),
+                       _req(922, 0.0, 2, rng)])
+        gc.collect()
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(str(tmp_path))
+    names = {n for _, _, n in spans}
+    assert names >= {"serve.submit", "serve.drain.m", "serve.group.m",
+                     "serve.prefill.m", "serve.decode.m", "serve.backfill.m",
+                     "serve.sample", "serve.retire", "serve.launch",
+                     "serve.sync", "serve.fetch", "serve.gc"}
+    assert not any("bench" in n for n in names)
+    prefills = [(s, e) for s, e, n in spans if n == "serve.prefill.m"]
+    assert len(prefills) == 1
+    s0, e0 = prefills[0]
+    inner = [n for s, e, n in spans if s0 <= s and e <= e0 and n !=
+             "serve.prefill.m"]
+    assert {"serve.launch", "serve.sync", "serve.fetch"} <= set(inner)
+    drain = [(s, e) for s, e, n in spans if n == "serve.drain.m"][0]
+    assert drain[0] <= s0 and e0 <= drain[1]
