@@ -39,7 +39,8 @@ class Request:
     model: str = field(compare=False, default="")
     # Wall-clock stamps (time.perf_counter() s) set by the batcher:
     # queued, given a slot, first token, retired. `start_exec`/`finish`
-    # above are the loop's per-drain virtual clock, which `run` replays.
+    # above are the loop's per-drain virtual clock, which starts at the
+    # protocol's present (0 in `run`'s replay).
     wall_queued: Optional[float] = field(compare=False, default=None)
     wall_start: Optional[float] = field(compare=False, default=None)
     wall_first: Optional[float] = field(compare=False, default=None)
@@ -98,7 +99,10 @@ class ContinuousBatcher:
     def form_group(self, now: float) -> Optional[List[Request]]:
         """Take up to batch_size arrived requests into a fresh group.
         (The aligned-decode engine prefills a whole group at once, so new
-        groups form only when the previous group has fully drained.)"""
+        groups form only when the previous group has fully drained.)
+        Arrived means `arrival <= now`: the caller's clock decides, and
+        `ServingLoop` passes the protocol's present or the earliest
+        queued arrival, whichever is later."""
         if self.n_active > 0:
             return None
         ready = []

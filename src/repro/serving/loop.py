@@ -55,6 +55,11 @@ class LoopStats:
 class ServingLoop:
     """Drives engines through a request trace in virtual time.
 
+    Clock: each drain seeds its first group at the protocol's present,
+    the largest `now` that `submit` has been given, or at the earliest
+    queued arrival if that is later. `run` gives no protocol time, so
+    its replay starts every drain at the earliest arrival.
+
     engines: {name: InferenceEngine}. The loop seeds an aligned group
     per model, then runs decode rounds with slot-level joins: a member
     retiring early frees its slot and the next queued arrival prefills
@@ -116,6 +121,8 @@ class ServingLoop:
                                         t_threshold=t_threshold)
         self.metrics = LoopMetrics()
         self.stats = LoopStats()
+        # The protocol's present: the largest `now` given to `submit`.
+        self._present = 0.0
         install_gc_span()
         self._req_modes: Dict[int, str] = {}
         # Optional trace capture (serving/trace.py, DESIGN.md §11):
@@ -142,8 +149,10 @@ class ServingLoop:
     def submit(self, req: Request, *, now: float = 0.0) -> StackOutcome:
         """Protocol admission: route (through the shared control step
         when a controller is attached) and queue on the chosen model's
-        batcher; execution and the metrics row land at `drain`."""
+        batcher; execution and the metrics row land at `drain`. A
+        request submitted at `now` has arrived by `now`."""
         with TraceAnnotation("serve.submit"):
+            self._present = max(self._present, now)
             return self._submit(req, now)
 
     def _submit(self, req: Request, now: float) -> StackOutcome:
@@ -166,7 +175,9 @@ class ServingLoop:
 
     def drain(self) -> None:
         """Drain each model's queue in arrival order (virtual clock per
-        model; engines measure real exec time on this host)."""
+        model; engines measure real exec time on this host). Each
+        model's clock starts at the protocol's present, so the first
+        group takes everything queued and due by then, up to a batch."""
         for name, batcher in self.batchers.items():
             self._drain(name, batcher)
 
@@ -210,7 +221,7 @@ class ServingLoop:
     def _serve_queue(self, name: str, batcher: ContinuousBatcher):
         eng = self.engines[name]
         stats = self.stats
-        now = 0.0
+        now = self._present
         # rid -> exec ms accumulated while the request occupied a slot.
         # Every engine call's wall time is charged to the requests that
         # were resident during it (aligned decode: they all stall

@@ -170,10 +170,10 @@ def test_backfill_schedule_counts_and_stamps(loop):
 
 
 def test_first_group_of_a_drain_takes_only_the_earliest(loop):
-    """Two requests queued before the drain, due 0 and 5 ms apart: the
-    drain's clock starts at the first arrival, so the first group seeds
-    one row and leaves the other queued behind it. The counter records
-    this seeding as it is today."""
+    """The replay clock: two requests queued with no protocol time
+    given, due 0 and 5 ms apart. The present stays 0, so the drain's
+    clock starts at the first arrival, the first group seeds one row and
+    the other waits behind it; the counter records both seedings."""
     rng = np.random.default_rng(5)
     reqs = [_req(910, 0.0, 1, rng), _req(911, 5.0, 1, rng)]
     got = _served(loop, reqs)
@@ -182,6 +182,85 @@ def test_first_group_of_a_drain_takes_only_the_earliest(loop):
     assert got["queued_at_group"] - got["group_rows"] == 1
     assert got["backfill_joins"] == 0
     assert all(_stamps_ordered(r) for r in reqs)
+
+
+@pytest.mark.parametrize("now", [5.0, 50.0])
+def test_group_seeds_at_the_protocols_present(loop, now):
+    """The same two requests submitted at a protocol time at or past
+    both arrivals: both have arrived by then, so one group seeds both
+    rows and leaves nothing behind."""
+    fresh = ServingLoop({"m": loop.engines["m"]})
+    rng = np.random.default_rng(5)
+    reqs = [_req(930, 0.0, 1, rng), _req(931, 5.0, 1, rng)]
+    for r in reqs:
+        fresh.submit(r, now=now)
+    fresh.drain()
+    s = fresh.stats
+    assert s.groups == 1 and s.group_rows == 2
+    assert s.queued_at_group - s.group_rows == 0
+    assert [r.start_exec for r in reqs] == [now, now]
+    assert sorted(rec["queue_ms"] for rec in fresh.metrics.records) == [
+        now - 5.0, now]
+
+
+def test_rounds_with_rising_present_never_start_early(loop):
+    """Submit/drain rounds as an open-loop client makes them, `now`
+    rising and each request due at or before the `now` it is submitted
+    at: no request starts before it arrives, and a drain's first group
+    leaves behind only what exceeds the batch."""
+    fresh = ServingLoop({"m": loop.engines["m"]})
+    batch = loop.engines["m"].batch_size
+    rng = np.random.default_rng(8)
+    reqs, now, rid = [], 0.0, 940
+    for _ in range(5):
+        prev, now = now, now + 20.0
+        before = dataclasses.replace(fresh.stats)
+        n = int(rng.integers(1, 2 * batch + 1))
+        for arrival in np.sort(rng.uniform(prev, now, n)):
+            r = _req(rid, float(arrival), int(rng.integers(1, 4)), rng)
+            fresh.submit(r, now=now)
+            reqs.append(r)
+            rid += 1
+        fresh.drain()
+        seeded = fresh.stats.group_rows - before.group_rows
+        assert seeded + fresh.stats.backfill_joins \
+            - before.backfill_joins == n
+        first = [r for r in reqs[-n:] if r.start_exec == now]
+        assert len(first) == min(n, batch)
+    assert all(len(r.tokens) == r.max_new_tokens for r in reqs)
+    assert all(r.start_exec >= r.arrival for r in reqs)
+    assert all(r.finish >= r.start_exec for r in reqs)
+
+
+# (arrival ms, max_new_tokens): three at once at batch 2, so a backfill,
+# then arrivals far enough apart that no measured engine time joins them.
+_REPLAY = [(0.0, 1), (0.0, 3), (0.0, 2), (1e6, 1), (2e6, 2), (2e6, 1),
+           (3e6, 2)]
+
+
+def test_replay_groups_as_before(loop):
+    """`run()` gives no protocol time, so it groups by the replay clock
+    exactly as before the present was kept: records (their measured
+    `queue_ms`, `exec_ms` and the `e2e_ms` summed from them aside),
+    seeding counts and seeding times pinned from the earlier rule."""
+    fresh = ServingLoop({"m": loop.engines["m"]})
+    rng = np.random.default_rng(7)
+    reqs = [_req(i, a, m, rng) for i, (a, m) in enumerate(_REPLAY)]
+    metrics = fresh.run(reqs)
+    measured = {"queue_ms", "exec_ms", "e2e_ms"}
+    got = [{k: v for k, v in rec.items() if k not in measured}
+           for rec in metrics.records]
+    assert got == [
+        {"rid": rid, "model": "m", "device": None, "mode": "static",
+         "ok": True, "tenant": None, "accuracy": None, "fallback": False,
+         "hedged": False, "replica": None}
+        for rid in (0, 2, 1, 3, 5, 4, 6)]
+    s = fresh.stats
+    assert (s.groups, s.group_rows, s.queued_at_group,
+            s.backfill_joins) == (4, 6, 15, 1)
+    assert [reqs[i].start_exec for i in (0, 2, 3, 4, 5, 6)] == [
+        0.0, 0.0, 1e6, 2e6, 2e6, 3e6]
+    assert reqs[1].start_exec > 0.0          # joined by backfill
 
 
 def test_fetch_time_grows_with_every_engine_call(loop):
