@@ -3,6 +3,10 @@
 These are the yardstick of every roofline share and utilization the
 benchmark reports: the same work whatever implements it, and never
 read from the lowered program. A multiply-add counts as two operations.
+
+A reference module's `counts(sizes)` returns the object the readers ask
+for a model's work; `Arch` is the dense decoder's. Any such object
+answers `prompt_ops(length)`, `token_ops(context)` and `int8_matmuls()`.
 """
 
 from __future__ import annotations
@@ -41,6 +45,19 @@ class Arch:
     @property
     def layer_matmul_params(self) -> int:
         return sum(k * n for k, n in self.projections())
+
+    def prompt_ops(self, length: int) -> float:
+        """Operations of a prompt of `length` real tokens."""
+        return prompt_flops(self, length)
+
+    def token_ops(self, context: int) -> float:
+        """Operations of one generated token at `context` keys."""
+        return token_flops(self, context, logits=True)
+
+    def int8_matmuls(self):
+        """(K, N, layers that have it) of each projection matmul that
+        the int8 kernel runs."""
+        return [(k, n, self.n_layers) for k, n in self.projections()]
 
 
 def token_flops(arch: Arch, context: int, *, logits: bool) -> float:

@@ -6,7 +6,9 @@ Everything that belongs to one configuration, traffic mix, cell or
 metric lives in a file of its own that this module finds by name:
 
   bench/configs/<config>.json      sizes, candidates, batch, reference
-  bench/references/<module>.py     the plain reference of those sizes
+  bench/references/<module>.py     the plain reference of those sizes:
+                                   make_weights, logit_stats, unmodelled,
+                                   counts
   bench/traffic/<mix>.json         parameters for bench/generate.py
   bench/cells/<workload>.json      the limits of the correctness check
   bench/metrics/<metric>.py        read(ctx) -> number or None
@@ -14,6 +16,8 @@ metric lives in a file of its own that this module finds by name:
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -33,13 +37,11 @@ for _p in (ROOT, os.path.join(ROOT, "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from bench import flops, generate, peaks, readers, trace_reduce  # noqa: E402
+from bench import generate, peaks, readers, trace_reduce  # noqa: E402
 
 CACHE_DIR = os.path.join(ROOT, ".jax_cache")
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")
-SIZE_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-             "d_ff", "vocab", "rotary_pct", "rope_theta", "norm_eps",
-             "mlp_gated")
+NOT_FIELDS = ("program_config", "source")   # keys of an `archs` entry
 CHECK_ROWS = 8            # rows per reference call
 CHECK_TOKENS = 400        # served tokens to compare per candidate, at least
 CHECK_MAX_REQUESTS = 256
@@ -87,9 +89,13 @@ def metric_entries(workload: str, trace: bool) -> list:
 
 
 def reference_module(cfg: dict):
-    return load_module(os.path.join(BENCH, "references",
-                                    f"{cfg['reference']}.py"),
-                       f"bench_ref_{cfg['reference']}")
+    return _reference(cfg["reference"])
+
+
+@functools.lru_cache(maxsize=None)
+def _reference(name: str):
+    return load_module(os.path.join(BENCH, "references", f"{name}.py"),
+                       f"bench_ref_{name}")
 
 
 def enable_compile_cache() -> None:
@@ -126,23 +132,50 @@ class Stamped(list):
 # Set-up
 # --------------------------------------------------------------------------
 
-def program_config(arch: dict):
-    """The program's model config for these sizes; refuses what the
-    reference does not model."""
+def program_config(cfg: dict, arch: dict):
+    """The program's model config for one `archs` entry of configuration
+    `cfg`: every key but NOT_FIELDS sets the field of that name (a dict
+    sets fields of a nested config, a list is a tuple). Refuses a key
+    that names no field, and what the configuration's reference does not
+    model."""
     from repro.configs import get_config
-    cfg = get_config(arch["program_config"], param_dtype="bfloat16",
-                     compute_dtype="bfloat16", attn_impl="pallas")
-    cfg = cfg.with_runtime(**{k: arch[k] for k in SIZE_KEYS})
-    plain = (cfg.pattern == ("attn",) and not cfg.tie_embeddings
-             and cfg.mlp_act == "silu" and not cfg.qk_norm
-             and not cfg.sandwich_norm and not cfg.window
-             and not cfg.attn_softcap and not cfg.final_softcap
-             and not cfg.embed_scale and not cfg.tp_pad_heads
-             and not cfg.tp_pad_vocab and cfg.input_mode == "tokens")
-    if not plain:
-        raise ValueError(f"{arch['program_config']}: the reference does not "
-                         f"model this architecture")
-    return cfg
+    pcfg = get_config(arch["program_config"], param_dtype="bfloat16",
+                      compute_dtype="bfloat16", attn_impl="pallas")
+    fields = {f.name for f in dataclasses.fields(pcfg)}
+    sizes = {}
+    for k, v in arch.items():
+        if k in NOT_FIELDS:
+            continue
+        if k not in fields:
+            raise ValueError(f"{cfg['name']}: {arch['program_config']} has "
+                             f"no field {k!r}")
+        sizes[k] = field_value(getattr(pcfg, k), v, k)
+    pcfg = pcfg.with_runtime(**sizes)
+    missing = reference_module(cfg).unmodelled(dataclasses.asdict(pcfg))
+    if missing:
+        raise ValueError(f"{cfg['name']}: reference {cfg['reference']} does "
+                         f"not model {arch['program_config']}'s "
+                         f"{', '.join(missing)}")
+    return pcfg
+
+
+def field_value(base, v, key: str):
+    """A configuration's value for a field that holds `base`."""
+    if isinstance(v, list):
+        return tuple(v)
+    if not isinstance(v, dict):
+        return v
+    if not dataclasses.is_dataclass(base):
+        raise ValueError(f"{key}: a dict of sizes for a field that holds "
+                         f"{base!r}, not a nested config")
+    fields = {f.name for f in dataclasses.fields(base)}
+    for k in v:
+        if k not in fields:
+            raise ValueError(f"{key}: {type(base).__name__} has no field "
+                             f"{k!r}")
+    return dataclasses.replace(base, **{k: field_value(getattr(base, k), x,
+                                                       f"{key}.{k}")
+                                        for k, x in v.items()})
 
 
 def build_engines(cfg: dict, seed: int):
@@ -156,7 +189,7 @@ def build_engines(cfg: dict, seed: int):
     weights, engines = {}, {}
     for name, m in cfg["models"].items():
         arch = cfg["archs"][m["arch"]]
-        pcfg = program_config(arch)
+        pcfg = program_config(cfg, arch)
         if m["weights"] not in weights:
             weights[m["weights"]] = ref.make_weights(
                 arch, seed_key(seed, m["weights"]))
@@ -492,8 +525,10 @@ class Context:
     trace: dict = None     # trace_reduce.reduce() of the traced window
     peaks: dict = None
 
-    def arch(self, model: str) -> flops.Arch:
-        return flops.Arch.from_sizes(
+    def arch(self, model: str):
+        """The counts of the configuration's reference for the model's
+        sizes (`bench/flops.py` says what they answer)."""
+        return reference_module(self.cfg).counts(
             self.cfg["archs"][self.cfg["models"][model]["arch"]])
 
     def due(self, r) -> float:
@@ -658,7 +693,7 @@ def program_control(cfg: dict, seed: int, checked: dict, n_tokens) -> dict:
         arch = cfg["archs"][m["arch"]]
         q = quantize_exec_tree(ref.make_weights(arch,
                                                 seed_key(seed, m["weights"])))
-        eng = InferenceEngine(program_config(arch), q, batch_size=B,
+        eng = InferenceEngine(program_config(cfg, arch), q, batch_size=B,
                               max_seq=cfg["max_seq"])
         picks, rows = {}, {}
         for lo in range(0, len(reqs), B):

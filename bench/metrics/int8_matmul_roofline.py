@@ -1,7 +1,8 @@
 """Roofline share of the int8 matmul kernel: per call, the larger of its
 operations at the int8 peak and its bytes at the HBM bandwidth, for
-every projection of every layer of the int8 candidates' calls, summed,
-over the kernel's device time in the trace."""
+every projection matmul the reference's counts name, times the layers
+that have it, of the int8 candidates' calls, summed, over the kernel's
+device time in the trace."""
 
 from bench import flops
 from bench.readers import kernel_roofline, window_calls
@@ -15,11 +16,11 @@ def least_time(ctx):
             continue
         a = ctx.arch(c.model)
         m = c.rows * c.tokens
-        for k, n in a.projections():
+        for k, n, layers in a.int8_matmuls():
             ops, nbytes = flops.int8_matmul_cost(m, k, n)
             t, _ = flops.roofline_time(ops, nbytes, pk["int8_ops"],
-                                           pk["hbm_bytes_per_s"])
-            total += a.n_layers * t
+                                       pk["hbm_bytes_per_s"])
+            total += layers * t
     return total
 
 

@@ -8,8 +8,6 @@ import math
 
 import numpy as np
 
-from bench import flops
-
 
 def nearest_rank(values, q: float) -> float:
     """The q-th percentile by nearest rank; inf counts as beyond all."""
@@ -40,16 +38,17 @@ def model_flops(ctx) -> float:
     """Operations the model needs for the real prompt and generated
     tokens of every request (padding excluded): the prompt's forward
     pass with logits at its last position, then one token per decode
-    step at its own context length."""
+    step at its own context length. The counts are the configuration's
+    reference's (`Context.arch`)."""
     total = 0.0
     for r in ctx.served.requests:
         if not r.tokens:
             continue
         arch = ctx.arch(r.model)
         L = min(len(r.prompt), ctx.cfg["prompt_len"])
-        total += flops.prompt_flops(arch, L)
+        total += arch.prompt_ops(L)
         for j in range(1, len(r.tokens)):
-            total += flops.token_flops(arch, L + j, logits=True)
+            total += arch.token_ops(L + j)
     return total
 
 

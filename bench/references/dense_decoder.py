@@ -13,6 +13,11 @@ layout the program's engines take (layers stacked on a leading axis).
 `bits` quantizes each projection weight per output channel, symmetric,
 to 2**(bits-1)-1 levels, the way an int8 (or int4) serving copy is made;
 the embedding, unembedding and norms stay as they are.
+
+What the harness asks of a reference module: `make_weights` and
+`logit_stats` (below), `unmodelled`, which names the fields of the
+program's config that this reference does not model, and `counts`, the
+yardstick's operation counts for these sizes.
 """
 
 from __future__ import annotations
@@ -21,6 +26,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from bench import flops
 
 # Trailing output axes of each projection (everything before them in one
 # layer's weight contracts).
@@ -50,6 +57,32 @@ def leaf_specs(s: dict):
 
 
 NORM_STD = 0.1   # norms scale by (1 + w), w ~ N(0, NORM_STD)
+
+
+def unmodelled(program: dict) -> list:
+    """The fields of the program's model config, given as
+    `dataclasses.asdict` of it, whose values this reference does not
+    model; empty where it models them all."""
+    p = program
+    plain = {"pattern": tuple(p["pattern"]) == ("attn",),
+             "tie_embeddings": not p["tie_embeddings"],
+             "mlp_act": p["mlp_act"] == "silu",
+             "mlp_gated": p["mlp_gated"],
+             "qk_norm": not p["qk_norm"],
+             "sandwich_norm": not p["sandwich_norm"],
+             "window": not p["window"],
+             "attn_softcap": not p["attn_softcap"],
+             "final_softcap": not p["final_softcap"],
+             "embed_scale": not p["embed_scale"],
+             "tp_pad_heads": not p["tp_pad_heads"],
+             "tp_pad_vocab": not p["tp_pad_vocab"],
+             "input_mode": p["input_mode"] == "tokens"}
+    return [k for k, ok in plain.items() if not ok]
+
+
+def counts(sizes: dict) -> flops.Arch:
+    """Operations and int8 matmuls of a dense decoder of these sizes."""
+    return flops.Arch.from_sizes(sizes)
 
 
 def init_weights(sizes: dict, key, dtype=jnp.bfloat16) -> dict:

@@ -1,9 +1,11 @@
 """Operation and byte counts against hand counts at a small shape, and
 the peak table."""
 
+import os
+
 import pytest
 
-from bench import flops, peaks
+from bench import flops, harness, peaks
 
 ARCH = flops.Arch(n_layers=2, d_model=8, n_heads=4, n_kv_heads=2,
                   head_dim=2, d_ff=16, vocab=10)
@@ -28,6 +30,23 @@ def test_prompt_flops_is_the_sum_of_its_tokens():
     by_token = sum(flops.token_flops(ARCH, i + 1, logits=False)
                    for i in range(n)) + 2 * 8 * 10
     assert flops.prompt_flops(ARCH, n) == pytest.approx(by_token)
+
+
+def test_the_counts_readers_ask_are_the_formulas():
+    assert ARCH.prompt_ops(5) == flops.prompt_flops(ARCH, 5)
+    assert ARCH.token_ops(7) == flops.token_flops(ARCH, 7, logits=True)
+    assert ARCH.int8_matmuls() == [(k, n, 2) for k, n in ARCH.projections()]
+
+
+def test_the_dense_reference_counts_at_published_width():
+    cfg = harness.load_json(os.path.join(harness.BENCH, "configs",
+                                         "stablelm-1.6b-zoo.json"))
+    a = harness.reference_module(cfg).counts(cfg["archs"]["stablelm-1.6b"])
+    assert [a.prompt_ops(n) for n in (32, 144, 256)] == [
+        79434874880.0, 357603737600.0, 638238851072.0]
+    assert a.token_ops(257) == 2927820800.0
+    assert a.int8_matmuls() == [(2048, 2048, 24)] * 4 + [
+        (2048, 5632, 24)] * 2 + [(5632, 2048, 24)]
 
 
 def test_int8_matmul_cost_by_hand():

@@ -1,12 +1,13 @@
 """The per-layer metric readers of a traced run, on a window built by
 hand: each number against its count from the definitions."""
 
+import os
 from types import SimpleNamespace
 
 import pytest
 from repro.serving.engine import EngineStats
 
-from bench import flops, harness
+from bench import flops, harness, readers
 from bench.tests import rehearse
 
 PEAKS = {"bf16_flops": 1e12, "int8_ops": 2e12, "hbm_bytes_per_s": 1e9}
@@ -73,6 +74,20 @@ def test_kernel_rooflines(ctx, got):
                 for o, b in (flops.int8_matmul_cost(m, k, n)
                              for k, n in a.projections()))
     assert got["int8_matmul_roofline"] == pytest.approx(100 * least / 1e-3)
+
+
+def test_counts_are_the_dense_formulas_to_the_last_digit(ctx, got):
+    """The readers take their counts from the reference's `counts`; on
+    this window they read what the dense formulas, called directly, gave
+    before."""
+    int8 = harness.load_module(
+        os.path.join(harness.BENCH, "metrics", "int8_matmul_roofline.py"),
+        "bench_metric_int8_matmul_roofline")
+    assert readers.model_flops(ctx) == 6753792.0
+    assert readers.mfu(ctx) == 0.0003376896
+    assert got["mfu"] == 0.0003376896
+    assert int8.least_time(ctx) == 0.0006963200000000002
+    assert got["int8_matmul_roofline"] == 69.632
 
 
 def test_engine_time_per_request_spans_every_call(ctx):
