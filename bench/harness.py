@@ -5,7 +5,8 @@ what the timed path produced against the plain reference.
 Everything that belongs to one configuration, traffic mix, cell or
 metric lives in a file of its own that this module finds by name:
 
-  bench/configs/<config>.json      sizes, candidates, batch, reference
+  bench/configs/<config>.json      sizes, candidates, batch, reference,
+                                   and optionally the mesh it runs on
   bench/references/<module>.py     the plain reference of those sizes:
                                    make_weights, logit_stats, unmodelled,
                                    counts
@@ -178,30 +179,125 @@ def field_value(base, v, key: str):
                                         for k, x in v.items()})
 
 
+def chips(cfg: dict) -> int:
+    """The chips a configuration runs on: its mesh's size, or 1 where it
+    states no mesh."""
+    m = cfg.get("mesh")
+    return math.prod(m["shape"]) if m else 1
+
+
+def check_chips(w: dict, cfg: dict) -> None:
+    if w["chips"] != chips(cfg):
+        raise ValueError(f"{w['name']}: the cell asks for {w['chips']} "
+                         f"chip(s), configuration {cfg['name']} runs on "
+                         f"{chips(cfg)} (its mesh's size; 1 without a mesh)")
+
+
+def parallel_of(cfg: dict):
+    """The program's serving ParallelConfig over the configuration's
+    `mesh` ({"shape", "axes"}), built by the program's own make_mesh and
+    make_parallel; None where the configuration states no mesh."""
+    m = cfg.get("mesh")
+    if m is None:
+        return None
+    import jax
+    from repro.launch.mesh import make_mesh
+    from repro.sharding import make_parallel
+    have = len(jax.devices())
+    if have < chips(cfg):
+        raise ValueError(f"{cfg['name']}: the mesh {m['shape']} needs "
+                         f"{chips(cfg)} devices, JAX sees {have}")
+    return make_parallel(make_mesh(m["shape"], m["axes"]), "serve")
+
+
+def make_weights(cfg: dict, arch: dict, seed: int, index: int, par):
+    """Weight set `index` from the seed by the reference's make_weights:
+    without a mesh, one jitted call on the default device; on a mesh,
+    each leaf made where the program's sharding rules place it, so that
+    no weight is ever whole on one device."""
+    ref = reference_module(cfg)
+    key = seed_key(seed, index)
+    if par is None:
+        return ref.make_weights(arch, key)
+    import jax
+    from repro.models import param_logical_axes
+    from repro.sharding import tree_shardings, tree_specs
+    pcfg = program_config(cfg, arch)
+    shardings = tree_shardings(tree_specs(param_logical_axes(pcfg), par,
+                                          pcfg), par.mesh)
+    check_same_tree(cfg, jax.eval_shape(
+        lambda k: ref.make_weights(arch, k), key), shardings)
+    return ref.make_weights(arch, key, out_shardings=shardings)
+
+
+def check_same_tree(cfg: dict, weights, shardings) -> None:
+    """Refuses, naming the first path that one has and the other lacks,
+    a reference's weight tree that is not the program's sharding tree."""
+    import jax
+    ref, prog = ([jax.tree_util.keystr(p) for p, _ in
+                  jax.tree_util.tree_flatten_with_path(t)[0]]
+                 for t in (weights, shardings))
+    only = ([(p, "the program", "the reference") for p in prog
+             if p not in ref]
+            + [(p, "the reference", "the program") for p in ref
+               if p not in prog])
+    if only:
+        path, has, lacks = only[0]
+        raise ValueError(
+            f"{cfg['name']}: reference {cfg['reference']}'s weights and "
+            f"the program's sharding tree differ first at {path}: "
+            f"{has} has it, {lacks} has not")
+
+
+def check_placed(name: str, weights, par) -> None:
+    """Refuses an engine's weight that does not lie on every device of
+    the mesh, and logs how many leaves are partitioned over it and how
+    many replicated."""
+    import jax
+    n = par.mesh.devices.size
+    split = whole = 0
+    for path, x in jax.tree_util.tree_flatten_with_path(weights)[0]:
+        if len(x.sharding.device_set) != n:
+            raise ValueError(
+                f"{name}: weight {jax.tree_util.keystr(path)} lies on "
+                f"{len(x.sharding.device_set)} device(s), not on the "
+                f"mesh's {n}")
+        if x.sharding.is_fully_replicated:
+            whole += 1
+        else:
+            split += 1
+    log(f"[mesh] {name}: {split} weight leaves partitioned over {n} "
+        f"devices, {whole} replicated on each")
+
+
 def build_engines(cfg: dict, seed: int):
     """Weights made on the device from the seed (one jitted program per
-    weight set), the int8 copies by the program's quantizer, and one
-    engine per candidate."""
+    weight set; on the configuration's mesh where it states one), the
+    int8 copies by the program's quantizer, and one engine per
+    candidate."""
     import jax
     from repro.quant.int8 import quantize_exec_tree
     from repro.serving.engine import InferenceEngine
-    ref = reference_module(cfg)
+    par = parallel_of(cfg)
     weights, engines = {}, {}
     for name, m in cfg["models"].items():
         arch = cfg["archs"][m["arch"]]
         pcfg = program_config(cfg, arch)
         if m["weights"] not in weights:
-            weights[m["weights"]] = ref.make_weights(
-                arch, seed_key(seed, m["weights"]))
+            weights[m["weights"]] = make_weights(cfg, arch, seed,
+                                                 m["weights"], par)
         p = weights[m["weights"]]
         if m["precision"] == "int8":
             p = quantize_exec_tree(p)
         elif m["precision"] != "bf16":
             raise ValueError(f"{name}: unknown precision {m['precision']!r}")
         jax.block_until_ready(p)
+        if par is not None:
+            check_placed(name, p, par)
         engines[name] = InferenceEngine(pcfg, p,
                                         batch_size=cfg["batch_size"],
-                                        max_seq=cfg["max_seq"])
+                                        max_seq=cfg["max_seq"],
+                                        parallel=par)
     return engines
 
 
@@ -359,7 +455,9 @@ def serve(loop, requests: list, seconds: float, served: Served) -> None:
                 r = pending.popleft()
                 with jax.profiler.TraceAnnotation("bench.submit"):
                     a = time.perf_counter()
-                    loop.submit(r, now=(a - t0) * 1e3)
+                    # the router names the candidate on the request; a
+                    # loop of one engine routes nothing
+                    r.model = loop.submit(r, now=(a - t0) * 1e3).model
                     b = time.perf_counter()
                 served.admission_s.append(b - a)
                 served.lateness_ms.append((a - t0) * 1e3 - r.arrival)
@@ -524,6 +622,7 @@ class Context:
     setup_s: float
     trace: dict = None     # trace_reduce.reduce() of the traced window
     peaks: dict = None
+    chips: int = 1         # the chips the configuration runs on
 
     def arch(self, model: str):
         """The counts of the configuration's reference for the model's
@@ -544,6 +643,13 @@ def read_metrics(entries: list, ctx: Context) -> dict:
         if v is not None:
             out[m["name"]] = {"value": float(v), "unit": m["unit"]}
     return out
+
+
+def memory_peak(devices) -> int:
+    """The peak bytes in use on the fullest of the devices (0 where the
+    backend keeps no count)."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devices)
 
 
 def stats_delta(engines: dict, before: dict) -> dict:
@@ -571,6 +677,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     if cfg is None:
         cfg = load_json(os.path.join(BENCH, "configs", f"{w['config']}.json"))
     mix = cell["mix"] if w is None else generate.load_mix(w["traffic"])
+    if w is not None:
+        check_chips(w, cfg)
     if cell is None:
         cell = load_json(os.path.join(BENCH, "cells", f"{workload}.json"))
     if entries is None:
@@ -604,8 +712,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
             trace_reduce.load(trace_reduce.newest_trace(TRACE_DIR)))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
     stats = stats_delta(engines, before)
-    mem = dev.memory_stats() or {}
-    device["memory_peak_bytes"] = int(mem.get("peak_bytes_in_use", 0))
+    device["memory_peak_bytes"] = memory_peak(jax.devices()[:chips(cfg)])
     if reduced is not None:
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
@@ -630,7 +737,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     ctx = Context(workload=workload, cfg=cfg, mix=mix, served=served,
                   stats=stats, setup_s=setup_s, trace=reduced,
                   peaks=peaks.peaks_for(dev.device_kind)
-                  if dev.platform == "tpu" else None)
+                  if dev.platform == "tpu" else None, chips=chips(cfg))
     log(f"[window] request p95 ms "
         f"{readers.nearest_rank([readers.e2e_ms(ctx, r) for r in requests], 95)}")
     metrics = read_metrics(entries, ctx)
@@ -683,7 +790,7 @@ def program_control(cfg: dict, seed: int, checked: dict, n_tokens) -> dict:
     its logit row} at the indexes the window kept)}."""
     from repro.quant.int8 import quantize_exec_tree
     from repro.serving.engine import InferenceEngine
-    ref = reference_module(cfg)
+    par = parallel_of(cfg)
     B, T = cfg["batch_size"], cfg["prompt_len"]
     out = {}
     for name, m in cfg["models"].items():
@@ -691,10 +798,10 @@ def program_control(cfg: dict, seed: int, checked: dict, n_tokens) -> dict:
         if m["precision"] != "bf16" or not reqs:
             continue
         arch = cfg["archs"][m["arch"]]
-        q = quantize_exec_tree(ref.make_weights(arch,
-                                                seed_key(seed, m["weights"])))
+        q = quantize_exec_tree(make_weights(cfg, arch, seed, m["weights"],
+                                            par))
         eng = InferenceEngine(program_config(cfg, arch), q, batch_size=B,
-                              max_seq=cfg["max_seq"])
+                              max_seq=cfg["max_seq"], parallel=par)
         picks, rows = {}, {}
         for lo in range(0, len(reqs), B):
             batch = reqs[lo:lo + B]
@@ -732,15 +839,17 @@ def reference_readings(cfg, seed, checked, kept, t_ref, n_rows, control,
     """Each candidate's numbers against the plain reference (its own
     precision's: f32, or int8 for an int8 candidate): the gaps of its
     served tokens (gap_max, gap_mean, gap_rms) and its kept logit rows
-    (logit_rms); with `control`, the same numbers of the control."""
+    (logit_rms); with `control`, the same numbers of the control. On a
+    mesh the reference's weights are placed as the program's are."""
     ref = reference_module(cfg)
+    par = parallel_of(cfg)
     readings, ctrl = {}, {}
     by_weights = {}
     for name, m in cfg["models"].items():
         by_weights.setdefault(m["weights"], []).append(name)
     for widx, names in sorted(by_weights.items()):
         arch = cfg["archs"][cfg["models"][names[0]]["arch"]]
-        weights = ref.make_weights(arch, seed_key(seed, widx))
+        weights = make_weights(cfg, arch, seed, widx, par)
         for name in names:
             reqs = checked[name]
             if not reqs:

@@ -53,10 +53,11 @@ def model_flops(ctx) -> float:
 
 
 def mfu(ctx):
-    """Model operations over the traced window at the chip's bf16 peak."""
+    """Model operations over the traced window at the bf16 peak of all
+    the chips the configuration runs on."""
     if ctx.trace is None or ctx.peaks is None:
         return None
-    return 100.0 * model_flops(ctx) / (ctx.trace["window_s"]
+    return 100.0 * model_flops(ctx) / (ctx.chips * ctx.trace["window_s"]
                                        * ctx.peaks["bf16_flops"])
 
 

@@ -101,9 +101,14 @@ def init_weights(sizes: dict, key, dtype=jnp.bfloat16) -> dict:
             "lm_head": flat["lm_head"], "blocks": (block,), "tail": ()}
 
 
-def make_weights(sizes: dict, key, dtype=jnp.bfloat16) -> dict:
-    """`init_weights` as one jitted program on the default device."""
-    return jax.jit(functools.partial(init_weights, sizes, dtype=dtype))(key)
+def make_weights(sizes: dict, key, dtype=jnp.bfloat16,
+                 out_shardings=None) -> dict:
+    """`init_weights` as one jitted program: on the default device, or,
+    given `out_shardings` (a tree of shardings shaped as the weights),
+    each leaf made where its sharding places it."""
+    kw = {} if out_shardings is None else {"out_shardings": out_shardings}
+    return jax.jit(functools.partial(init_weights, sizes, dtype=dtype),
+                   **kw)(key)
 
 
 def fake_quant(w, out_axes: int, bits: int):

@@ -1,6 +1,7 @@
 """The per-layer metric readers of a traced run, on a window built by
 hand: each number against its count from the definitions."""
 
+import dataclasses
 import os
 from types import SimpleNamespace
 
@@ -88,6 +89,21 @@ def test_counts_are_the_dense_formulas_to_the_last_digit(ctx, got):
     assert got["mfu"] == 0.0003376896
     assert int8.least_time(ctx) == 0.0006963200000000002
     assert got["int8_matmul_roofline"] == 69.632
+
+
+def test_mfu_divides_by_the_chips_of_the_configuration(ctx):
+    """Four chips have four times one chip's peak; the int8 kernel's
+    roofline sums its time over the chips and is left as it is."""
+    four = dataclasses.replace(ctx, chips=4)
+    assert ctx.chips == 1
+    assert readers.mfu(four) == readers.mfu(ctx) / 4
+    assert readers.mfu(ctx) == 100.0 * readers.model_flops(ctx) / (
+        ctx.trace["window_s"] * ctx.peaks["bf16_flops"])
+    got4 = harness.read_metrics(harness.metric_entries(rehearse.CELL, True),
+                                four)
+    got1 = harness.read_metrics(harness.metric_entries(rehearse.CELL, True),
+                                ctx)
+    assert got4["int8_matmul_roofline"] == got1["int8_matmul_roofline"]
 
 
 def test_engine_time_per_request_spans_every_call(ctx):

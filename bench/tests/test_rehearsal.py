@@ -66,6 +66,17 @@ def test_one_token_requests_are_checked():
                for c in result["checks"].values())
 
 
+def test_a_configuration_of_one_candidate_is_served_and_checked():
+    """A loop of one engine routes nothing: the harness names the
+    candidate on each request itself."""
+    result = rehearse.run(only="tiny_bf16")
+    assert result["correct"] is True and result["attempted"] == 18
+    assert result["metrics"]["accuracy_mean"]["value"] == 1.0
+    assert sorted(result["checks"]) == ["gap_max.tiny_bf16",
+                                        "gap_mean.tiny_bf16"]
+    assert all(c["value"] is not None for c in result["checks"].values())
+
+
 def test_control_fails_the_limits(result):
     """The int8 candidate's control, the reference computed at int4 in
     its place, is not correct by the cell's limits. (The bf16
