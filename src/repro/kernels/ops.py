@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.decode_attention import decode_attention as _decode
 from repro.kernels.int8_matmul import int8_matmul as _int8mm
+from repro.kernels.int8_matmul import tiles as int8_tiles
 
 
 def _interpret() -> bool:
@@ -89,10 +90,15 @@ def decode_attention(q, k, v, pos, cache_pos, valid_from=None, *, window=0,
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k"))
-def int8_matmul(x, w_q, w_scale, *, block_m=256, block_n=256, block_k=512):
+def int8_matmul(x, w_q, w_scale, *, block_m=None, block_n=None, block_k=None):
+    """Blocks not given come from the kernel's tile rule for this shape
+    and dtype. Operands pad to block multiples; under the tile rule only
+    a dimension with no aligned divisor pads."""
     M, K = x.shape
     N = w_q.shape[1]
-    bm, bn, bk = min(block_m, M), min(block_n, N), min(block_k, K)
+    tm, tn, tk = int8_tiles(M, K, N, x.dtype)
+    bm, bn, bk = (min(block_m or tm, M), min(block_n or tn, N),
+                  min(block_k or tk, K))
     pm, pn, pk = (-M) % bm, (-N) % bn, (-K) % bk
     xp = jnp.pad(x, ((0, pm), (0, pk))) if (pm or pk) else x
     wp = jnp.pad(w_q, ((0, pk), (0, pn))) if (pk or pn) else w_q

@@ -221,6 +221,68 @@ def test_int8_matmul_vs_ref(mnk, dtype, rng):
                                rtol=tol)
 
 
+# (K, N) of stablelm-1.6b's projections (q/k/v/o, gate/up, down), then
+# yi-9b's MLP (d_model 4096, d_ff 11008 = 2^8 * 43).
+STABLELM_KN = [(2048, 2048), (2048, 5632), (5632, 2048)]
+INT8_KN = STABLELM_KN + [(4096, 11008), (11008, 4096)]
+
+
+@pytest.mark.parametrize("K,N", INT8_KN)
+@pytest.mark.parametrize("M", [4, 16, 256, 4096])
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_int8_tiles_fit(M, K, N, dtype):
+    """The wrapper's own tiles: each divides its dimension, or the
+    dimension has no aligned divisor and pads by less than one block;
+    nothing pads at stablelm widths; the double-buffered estimate lies
+    under the VMEM limit the kernel sets, and that under a v5e's
+    128 MiB."""
+    from repro.kernels import int8_matmul as K8
+    bm, bn, bk = K8.tiles(M, K, N, dtype)
+    sublanes = 32 // jnp.dtype(dtype).itemsize
+    for d, t, align in ((M, bm, sublanes), (N, bn, 128), (K, bk, 128)):
+        assert t == d or t % align == 0
+        assert d % t == 0 or d % align, (d, t)
+        assert (-d) % t < t
+    if (K, N) in STABLELM_KN:
+        assert M % bm == 0 and N % bn == 0 and K % bk == 0
+    n_k = -(-K // bk)
+    need = K8.vmem_bytes(bm, bn, bk, n_k, dtype)
+    assert need < K8.vmem_limit(bm, bn, bk, n_k, dtype) <= 128 * 2**20
+
+
+@pytest.mark.parametrize("K,N", STABLELM_KN)
+def test_int8_tiles_traffic_under_compute(K, N):
+    """At a 16 x 256 bf16 prefill, the tiles' HBM traffic (x re-read per
+    N block, Wq per M block, C written once) takes less time at a v5e's
+    819 GB/s than the bf16 MXU needs for the products at 197 TFLOP/s."""
+    from repro.kernels import int8_matmul as K8
+    M = 4096
+    bm, bn, bk = K8.tiles(M, K, N, jnp.bfloat16)
+    traffic = M * K * 2 * (N // bn) + K * N * (M // bm) + M * N * 2
+    assert traffic / 819e9 < 2 * M * K * N / 197e12
+
+
+@pytest.mark.parametrize("mnk", [(512, 384, 1408),   # one K block
+                                 (512, 384, 2816),   # two K blocks
+                                 (16, 1100, 256),    # N pads
+                                 (1100, 128, 256)])  # M pads
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_int8_matmul_own_tiles_vs_ref(mnk, dtype, rng):
+    M, N, K = mnk
+    x = jnp.asarray(rng.normal(size=(M, K)), dtype)
+    w = jnp.asarray(rng.normal(size=(K, N)), jnp.float32)
+    wq, sc = quantize_int8(w, axis=0)
+    out = ops.int8_matmul(x, wq, sc.reshape(-1))
+    assert out.shape == (M, N) and out.dtype == dtype
+    ref = np.asarray(R.int8_matmul_ref(x, wq, sc.reshape(-1)), np.float32)
+    # f32 sums of K terms differ by block order: the absolute part of the
+    # tolerance is relative to the output's RMS (about sqrt(K)).
+    tol = 1e-4 if dtype == jnp.float32 else 5e-2
+    rms = float(np.sqrt(np.mean(ref ** 2)))
+    np.testing.assert_allclose(np.asarray(out, np.float32), ref,
+                               atol=tol * rms, rtol=tol)
+
+
 def test_int8_quantization_error_small(rng):
     """End-to-end: int8 matmul approximates the fp32 matmul (paper Fig 6:
     small accuracy cost for 75% storage saving)."""

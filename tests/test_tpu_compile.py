@@ -89,3 +89,21 @@ def test_int8_matmul_compiles(one_chip, M):
         one_chip, ((M, K), jnp.bfloat16), ((K, N), jnp.int8),
         ((N,), jnp.float32))
     assert "tpu_custom_call" in txt
+
+
+# The wrapper with its own tiles (no block_*), at decode (M = batch 16),
+# the backfill row (256) and a 16 x 256 prefill, for stablelm-1.6b's
+# q/k/v/o, gate/up and down widths: a tile that asks more VMEM than
+# Mosaic allows fails here. The wrapper asks the backend whether to
+# interpret, and the backend here is the CPU: the test says no.
+@pytest.mark.parametrize("K,N", [(2048, 2048), (2048, 5632), (5632, 2048)])
+@pytest.mark.parametrize("M", [16, 256, 4096])
+def test_int8_matmul_own_tiles_compile(one_chip, monkeypatch, M, K, N):
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    txt = _compiled_text(
+        lambda x, w, s: ops.int8_matmul.__wrapped__(x, w, s),
+        one_chip, ((M, K), jnp.bfloat16), ((K, N), jnp.int8),
+        ((N,), jnp.float32))
+    assert "tpu_custom_call" in txt
+    assert " pad(" not in txt
