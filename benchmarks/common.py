@@ -13,15 +13,6 @@ RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
-def configure_host(host_devices: int | None = None, x64: bool | None = None,
-                   platform: str | None = None) -> None:
-    """Benchmark entry points call this before touching jax so XLA
-    flags (fake host device count, platform pin) actually apply —
-    see repro.utils.config.configure for the rules."""
-    from repro.utils.config import configure
-    configure(platform=platform, x64=x64, host_devices=host_devices)
-
-
 def time_call(fn, *args, reps: int = 5, warmup: int = 1):
     """Returns (mean_us, std_us) of fn(*args)."""
     import numpy as np

@@ -26,8 +26,6 @@ MODULES = [
     ("fig10x_network_dynamics", "benchmarks.network_dynamics"),
     ("table4x_fleet_dynamics", "benchmarks.fleet_dynamics"),
     ("ctrl_adaptive_control", "benchmarks.adaptive_control"),
-    ("engine_scale", "benchmarks.engine_scale"),
-    ("cluster_scale", "benchmarks.cluster_scale"),
     ("sim2real_trace_replay", "benchmarks.trace_replay"),
     ("fig12_prototype_e2e", "benchmarks.prototype_e2e"),
     ("fig13_selection_vs_greedy", "benchmarks.selection_vs_greedy"),

@@ -231,32 +231,6 @@ TENANT_MIXES = {
 }
 
 
-def scale_tenant_mix(n_devices: int, *, seed: int = 0):
-    """A three-class tenant mix whose fleets total `n_devices`
-    devices, for the cluster-scale sweep (benchmarks/cluster_scale.py:
-    1k -> 1M tenant-devices). ``"array:<n>:<seed>"`` fleet specs keep
-    per-device state columnar at any size — the FLEET_SCENARIOS
-    mixtures enumerate device-id strings, which a 10^6-device
-    population would pay for in memory and workload-generation time.
-    Shares/phases mirror ``consumer_burst`` (staggered burst peaks per
-    SLA class). Deliberately NOT a `TENANT_MIXES` entry: the registry
-    names fixed paper-figure scenarios, this one is parameterized by
-    scale. Returns the tenant-spec list `make_tenants` (and every
-    workload/cluster constructor) accepts."""
-    if n_devices < 3:
-        raise ValueError(f"scale mix needs >= 3 devices (one per SLA "
-                         f"class), got {n_devices}")
-    classes = (("gold", 0.3, 0.0), ("silver", 0.4, 0.4),
-               ("bronze", 0.3, 0.7))
-    base = n_devices // len(classes)
-    counts = [base, base, n_devices - 2 * base]
-    return [
-        dict(tenant=f"{cls}-scale", sla_class=cls,
-             fleet=f"array:{n}:{seed + k}", weight=w, phase=ph,
-             burst=4.0)
-        for k, ((cls, w, ph), n) in enumerate(zip(classes, counts))]
-
-
 # Named adaptive-controller presets for `serving.control.make_controller`
 # (`SimConfig.controller`, CNNSelectServer/ServingLoop `controller=`):
 # an ordered mode table (core.selection.CONTROL_MODES names, least ->

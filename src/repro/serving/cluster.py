@@ -122,12 +122,11 @@ def make_tenants(mix: Union[str, Sequence]) -> List[TenantSpec]:
 
 @dataclass
 class TenantColumns:
-    """Columnar multi-tenant workload (the scan cluster engine's view).
+    """Columnar multi-tenant workload.
 
-    Devices live in one global *column* universe: tenant ``t``'s
-    devices occupy columns ``[col_offsets[t], col_offsets[t+1])`` in
-    fleet order, so per-column arrays (`col_prior`, `col_od_ms`) line
-    up with the controller's device axis. Request rows are sorted by
+    Devices live in one global *column* universe: each tenant's
+    devices occupy a contiguous block of columns in fleet order, in
+    mix order. Request rows are sorted by
     ``(arrival, tenant name)`` — exactly `make_tenant_workload`'s
     ordering — with row i playing rid i."""
 
@@ -136,11 +135,7 @@ class TenantColumns:
     t_input: np.ndarray       # (N,) f64
     col: np.ndarray           # (N,) int64 global device column
     tenant_idx: np.ndarray    # (N,) int64 into `tenants`
-    sla_ms: np.ndarray        # (N,) f64 per-request deadline
-    col_offsets: np.ndarray   # (T+1,) int64
     col_tenant: np.ndarray    # (D,) int64 owning tenant per column
-    col_prior: np.ndarray     # (D,) f64 long-run mean T_input
-    col_od_ms: np.ndarray     # (D,) f64 on-device latency (0 = none)
     col_local: List           # per-column local device token (str|int)
 
     def __len__(self) -> int:
@@ -152,33 +147,22 @@ class TenantColumns:
         t = self.tenants[self.col_tenant[c]]
         return f"{t.name}/{self.col_local[c]}"
 
-    def __getitem__(self, c: int) -> str:
-        """Index-as-name view, so the columns object itself can serve
-        as the scan engine's `device_names` table without materializing
-        D id strings."""
-        return self.device_name(c)
 
-
-def _tenant_fleet_columns(fleet):
-    """``(local_tokens, prior, od_ms)`` for one tenant's fleet —
-    `FleetMixture` devices are keyed by id string, `ArrayFleet`
-    devices by integer index (materializing 10^6 id strings would
-    dwarf the workload)."""
+def _tenant_fleet_columns(fleet) -> List:
+    """The local device tokens of one tenant's fleet — `FleetMixture`
+    devices are keyed by id string, `ArrayFleet` devices by integer
+    index (materializing 10^6 id strings would dwarf the workload)."""
     if isinstance(fleet, ArrayFleet):
-        local = list(range(fleet.n_devices))
-    else:
-        local = list(fleet.device_ids)
-    return local, fleet.prior_array(), fleet.on_device_arrays()[0]
+        return list(range(fleet.n_devices))
+    return list(fleet.device_ids)
 
 
 def make_tenant_columns(mix: Union[str, Sequence], *,
                         n_requests: int, rate_hz: float,
                         seed: int = 0) -> TenantColumns:
     """`make_tenant_workload`'s sampler in columnar form: all-array
-    arrival/T_input/device generation (no per-request python loop) plus
-    the per-column prior / on-device tables. `make_tenant_workload`
-    materializes `Request`s from this; the scan cluster engine consumes
-    it directly."""
+    arrival/T_input/device generation (no per-request python loop).
+    `make_tenant_workload` materializes `Request`s from this."""
     tenants = make_tenants(mix)
     horizon_ms = n_requests / float(rate_hz) * 1000.0
     total_w = sum(t.weight for t in tenants)
@@ -186,12 +170,10 @@ def make_tenant_columns(mix: Union[str, Sequence], *,
     # the controller priors prime every tenant's devices).
     fleets = [make_fleet(t.fleet) for t in tenants]
     per = [_tenant_fleet_columns(f) for f in fleets]
-    col_offsets = np.cumsum([0] + [len(p[0]) for p in per])
+    col_offsets = np.cumsum([0] + [len(p) for p in per])
     col_tenant = np.repeat(np.arange(len(tenants), dtype=np.int64),
-                           [len(p[0]) for p in per])
-    col_local = [tok for p in per for tok in p[0]]
-    col_prior = np.concatenate([p[1] for p in per])
-    col_od = np.concatenate([p[2] for p in per])
+                           [len(p) for p in per])
+    col_local = [tok for p in per for tok in p]
     arr_parts, ti_parts, col_parts, tid_parts = [], [], [], []
     root = np.random.SeedSequence(seed)
     for ti, (t, ss) in enumerate(zip(tenants,
@@ -223,16 +205,12 @@ def make_tenant_columns(mix: Union[str, Sequence], *,
     name_rank = np.argsort(
         np.argsort([t.name for t in tenants])).astype(np.int64)
     order = np.lexsort((name_rank[tenant_idx], arrival))
-    t_sla = np.array([t.t_sla for t in tenants], np.float64)
-    tenant_idx = tenant_idx[order]
     return TenantColumns(
         tenants=tenants, arrival=arrival[order],
         t_input=np.concatenate(ti_parts)[order],
         col=np.concatenate(col_parts)[order],
-        tenant_idx=tenant_idx, sla_ms=t_sla[tenant_idx],
-        col_offsets=col_offsets.astype(np.int64),
-        col_tenant=col_tenant, col_prior=col_prior,
-        col_od_ms=col_od, col_local=col_local)
+        tenant_idx=tenant_idx[order], col_tenant=col_tenant,
+        col_local=col_local)
 
 
 def make_tenant_workload(mix: Union[str, Sequence], *,
@@ -370,15 +348,9 @@ class Cluster:
                  controller: Union[str, AdaptiveController,
                                    None] = "reactive",
                  hedge: bool = True, shed_factor: float = 1.0,
-                 scale_headroom: float = 0.25, min_active: int = 1,
-                 engine: str = "python", shards: int = 1):
+                 scale_headroom: float = 0.25, min_active: int = 1):
         if not replicas:
             raise ValueError("cluster needs at least one replica")
-        if engine not in ("python", "scan"):
-            raise ValueError(f"unknown cluster engine {engine!r}; "
-                             f"known: python, scan")
-        self.engine = engine
-        self.shards = int(shards)
         self.replicas = list(replicas)
         self.tenants = {t.name: t for t in make_tenants(tenants)}
         self.events: List[dict] = []
@@ -407,7 +379,7 @@ class Cluster:
         # queue delays per request, and invalidates only the replicas
         # it touched. The delay expression is the replica's own
         # (max(0, free - arrive)), so cached decisions are bit-for-bit
-        # the uncached ones (pinned by tests/test_cluster_engine.py).
+        # the uncached ones.
         self._free_cache: List[Optional[float]] = [None] * len(replicas)
         self._cap_cache: List[Optional[float]] = [None] * len(replicas)
 
@@ -562,13 +534,7 @@ class Cluster:
     # -- convenience --------------------------------------------------
     def run(self, requests) -> ServingMetrics:
         """Serve a workload — a `Request` sequence or a
-        `TenantColumns` — through the configured engine. The scan
-        engine (serving/cluster_engine.py) reproduces the python
-        loop's events and metrics bit-for-bit."""
-        if self.engine == "scan":
-            from repro.serving.cluster_engine import scan_cluster_run
-            scan_cluster_run(self, requests, shards=self.shards)
-            return self.metrics
+        `TenantColumns` — in arrival order, then drain."""
         if isinstance(requests, TenantColumns):
             requests = requests_from_columns(requests)
         for req in sorted(requests, key=lambda r: r.arrival):

@@ -725,17 +725,6 @@ class ControlPlane:
                      prior_mean, on_device, n) -> BatchPlan:
         t_est = self.router.estimate_series(t_inputs,
                                             device_ids=est_keys)
-        return self.finish_static(rng, t_sla, t_est, realized,
-                                  prior_mean, on_device, n)
-
-    def finish_static(self, rng, t_sla, t_est, realized, prior_mean,
-                      on_device, n) -> BatchPlan:
-        """Phase 2 of the static plan — selection, outage masks, and
-        the fallback-latency draws — over already-materialized budget
-        estimates. Split from the estimation phase so the scan engine
-        (serving/scan_engine.py) can compute `t_est` with its array
-        program and then share this exact selection/masking code (and
-        its RNG consumption order) with the python path."""
         sel = np.asarray(self.router.route_batch(
             np.full(n, t_sla), t_est, realized=realized,
             estimated=True), np.int64)
@@ -771,32 +760,11 @@ class ControlPlane:
             bank = self._bank_for(spec)
             series[spec] = (t_inputs.copy() if bank is None else
                             bank.estimate_series(t_inputs, est_keys))
-        t_est = self.compose_adaptive_estimates(series, modes_idx, n)
-        return self.finish_adaptive(rng, t_sla, t_est, modes_idx,
-                                    ctrl.events, realized, prior_mean,
-                                    on_device, n)
-
-    def compose_adaptive_estimates(self, series: Dict, modes_idx,
-                                   n: int) -> np.ndarray:
-        """Each request's budget estimate read from the series of its
-        governing mode's estimator spec (shared by both engines)."""
         t_est = np.empty(n, np.float64)
-        for k, m in enumerate(self.controller.modes):
+        for k, m in enumerate(ctrl.modes):
             mask = modes_idx == k
             if mask.any():
                 t_est[mask] = series[m.t_estimator][mask]
-        return t_est
-
-    def finish_adaptive(self, rng, t_sla, t_est, modes_idx, events,
-                        realized, prior_mean, on_device,
-                        n) -> BatchPlan:
-        """Phase 2 of the adaptive plan — per-mode selection, hedging
-        gates, fallback masks/draws — over already-materialized budget
-        estimates and per-request mode indices. The scan engine feeds
-        this with its array-program outputs; the RNG consumption order
-        (the one `rng.normal` fallback-latency draw) is identical to
-        the python path's."""
-        ctrl = self.controller
         mode_list = ctrl.modes
         # Selection: requests grouped by governing policy (base policy
         # for modes that do not override it).
@@ -855,4 +823,4 @@ class ControlPlane:
             fb_mask=fb_mask, od_latency=od_latency,
             od_accuracy=od_accuracy, modes=np.asarray(modes_idx,
                                                       np.int64),
-            mode_names=ctrl.mode_names(), events=list(events))
+            mode_names=ctrl.mode_names(), events=list(ctrl.events))

@@ -142,7 +142,7 @@ class FleetMixture:
 
     def prior_array(self) -> np.ndarray:
         """`priors()` in device-index order — the (D,) array form the
-        scan engine (and the simulator's per-request gather) consume."""
+        simulator's per-request gather consumes."""
         return np.array([p.mean for p in self.processes], np.float64)
 
     def on_device_arrays(self):
@@ -226,7 +226,7 @@ class FleetMixture:
 
 
 class ArrayFleet:
-    """Vectorized fleet for million-device populations (DESIGN.md §13).
+    """Vectorized fleet for million-device populations.
 
     `FleetMixture` models a handful of *tiers* faithfully (independent
     child RNG streams, regime-switching radios) but draws each device's
@@ -238,8 +238,8 @@ class ArrayFleet:
     stays meaningful), and a whole trace is one vectorized lognormal
     draw. Devices are identified by their integer index; the fleet
     protocol (`prior_array` / `on_device_arrays` / `sample_trace` /
-    `priors` / `mean`) matches `FleetMixture`, so both engines accept
-    either class. Tier membership is deterministic (contiguous blocks
+    `priors` / `mean`) matches `FleetMixture`, so `simulate()` and
+    `Cluster` accept either class. Tier membership is deterministic (contiguous blocks
     proportional to `tier_weights`); the per-device jitter is fixed by
     `seed` at construction, so two fleets built with the same arguments
     are identical."""
@@ -292,8 +292,7 @@ class ArrayFleet:
         return self._od
 
     def priors(self) -> Dict[int, float]:
-        """Dict form of `prior_array` (python-engine bank priors).
-        O(D) — the scan engine uses `prior_array` directly."""
+        """Dict form of `prior_array` (the estimator-bank priors)."""
         return dict(enumerate(self.device_mean))
 
     def regime_names(self) -> List[str]:

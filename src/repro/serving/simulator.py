@@ -104,19 +104,6 @@ class SimConfig:
     # With a controller, `t_estimator`/`hedge` above configure nothing:
     # the active mode's table entries govern each request.
     controller: Union[str, AdaptiveController, None] = None
-    # Simulation engine (DESIGN.md §13). "python": the per-request
-    # reference loop (golden-pinned). "scan": the jit-compiled
-    # `lax.scan` array program over per-device state columns
-    # (serving/scan_engine.py) — same decisions, modes, and events;
-    # estimator-derived floats agree to the estimator-series ULP
-    # tolerance. Requires registry-spec estimators/detectors (or cold
-    # instances) and no memory budget.
-    engine: str = "python"
-    # Shard the device axis of the scan program across this many jax
-    # devices (jax.shard_map; bitwise identical to shards=1).
-    # CPU runs get a mesh via repro.utils.config.configure(
-    # host_devices=N) before jax initializes.
-    shards: int = 1
 
 
 @dataclass
@@ -286,11 +273,7 @@ def _make_sim_estimator(cfg: SimConfig, fleet: Optional[FleetMixture],
     if spec is None:
         return None
     if fleet is not None:
-        # The scan engine reads per-device priors from the fleet's
-        # `prior_array` directly; materializing the O(D) dict here
-        # would dominate setup at a million devices.
-        priors = fleet.priors() if cfg.engine == "python" else {}
-        return EstimatorBank(spec, priors=priors,
+        return EstimatorBank(spec, priors=fleet.priors(),
                              default_prior=fleet.mean,
                              lag=cfg.estimator_lag)
     # Single shared process but a stale (lagged) view: one bank entry.
@@ -307,15 +290,6 @@ def simulate(profiles: Sequence[ModelProfile], cfg: SimConfig, *,
     actually ran, so its column is filled and the rest stay NaN
     (sampled from the profile as usual)."""
     rng = np.random.default_rng(cfg.seed)
-    if cfg.engine not in ("python", "scan"):
-        raise ValueError(f"unknown engine {cfg.engine!r}; known: "
-                         f"python, scan")
-    if cfg.engine == "scan" and cfg.memory_budget_bytes is not None:
-        raise ValueError("engine='scan' does not model the zoo memory "
-                         "budget (LRU eviction is request-sequential); "
-                         "use engine='python'")
-    if cfg.shards < 1:
-        raise ValueError(f"shards must be >= 1, got {cfg.shards}")
     fleet = make_fleet(cfg.fleet)
     net = make_network(cfg.network) if fleet is None else None
     hedge = _hedge_mode(cfg)
@@ -344,9 +318,7 @@ def simulate(profiles: Sequence[ModelProfile], cfg: SimConfig, *,
     plane = ControlPlane(
         router, hedge=hedge, outage_factor=cfg.outage_factor,
         on_device_fallback=cfg.on_device_fallback, controller=ctrl,
-        priors=(fleet.priors()
-                if fleet is not None and cfg.engine == "python"
-                else {}),
+        priors=fleet.priors() if fleet is not None else {},
         default_prior=fleet.mean if fleet is not None else net.mean,
         lag=cfg.estimator_lag, seed=policy_seed,
         t_threshold=cfg.t_threshold, stage2_variant=cfg.stage2_variant)
@@ -357,7 +329,6 @@ def simulate(profiles: Sequence[ModelProfile], cfg: SimConfig, *,
         device_index = device_keys = None
         regime_names = net.regime_names()
         device_ids: Optional[List[str]] = None
-        prior_vec = None
         prior_mean = np.full(N, net.mean)
     else:
         ftrace = fleet.sample_trace(rng, N)
@@ -366,8 +337,7 @@ def simulate(profiles: Sequence[ModelProfile], cfg: SimConfig, *,
         device_keys = ftrace.device_keys()
         regime_names = ftrace.regime_names
         device_ids = ftrace.device_ids
-        prior_vec = fleet.prior_array()
-        prior_mean = prior_vec[device_index]
+        prior_mean = fleet.prior_array()[device_index]
     # Pre-sample each model's hypothetical execution time per request so
     # the oracle and the actual run see consistent draws.
     exec_samples = np.empty((N, len(profiles)))  # (N, K), column-filled
@@ -404,36 +374,16 @@ def simulate(profiles: Sequence[ModelProfile], cfg: SimConfig, *,
         od_ms, od_sg, od_acc = fleet.on_device_arrays()
         on_device = (od_ms[device_index], od_sg[device_index],
                      od_acc[device_index])
-    if cfg.engine == "scan":
-        from repro.serving.scan_engine import scan_plan_batch
-        plan = scan_plan_batch(
-            plane, rng, cfg.t_sla, t_inputs,
-            device_index=device_index,
-            prior_vec=prior_vec if fleet is not None else None,
-            device_names=device_ids,
-            estimator_scope=cfg.estimator_scope,
-            realized=exec_samples, prior_mean=prior_mean,
-            on_device=on_device, shards=cfg.shards)
-    else:
-        plan = plane.plan_batch(rng, cfg.t_sla, t_inputs,
-                                device_keys=device_keys,
-                                realized=exec_samples,
-                                prior_mean=prior_mean,
-                                on_device=on_device,
-                                estimator_scope=cfg.estimator_scope)
+    plan = plane.plan_batch(rng, cfg.t_sla, t_inputs,
+                            device_keys=device_keys,
+                            realized=exec_samples,
+                            prior_mean=prior_mean,
+                            on_device=on_device,
+                            estimator_scope=cfg.estimator_scope)
     sel = plan.sel
     degraded, fb_mask = plan.degraded, plan.fb_mask
     od_latency, od_accuracy = plan.od_latency, plan.od_accuracy
 
-    if cfg.engine == "scan":
-        from repro.serving.scan_engine import scan_event_phase
-        lat, sel, hedges, fallbacks = scan_event_phase(
-            cfg, plan, t_inputs, arrivals, exec_samples, profiles,
-            zoo, rng)
-        return _assemble_result(cfg, plan, lat, sel, hedges,
-                                fallbacks, zoo, profiles, regimes,
-                                regime_names, degraded, device_index,
-                                device_ids, t_inputs, arrivals)
     lat = np.zeros(N)
     hedges = fallbacks = 0
     now = 0.0
@@ -471,23 +421,11 @@ def simulate(profiles: Sequence[ModelProfile], cfg: SimConfig, *,
         else:
             queue = 0.0  # closed loop: requests are independent
         lat[i] = ti + queue + exec_t + ti  # up + queue + exec + down
-    return _assemble_result(cfg, plan, lat, sel, hedges, fallbacks,
-                            zoo, profiles, regimes, regime_names,
-                            degraded, device_index, device_ids,
-                            t_inputs, arrivals)
-
-
-def _assemble_result(cfg, plan, lat, sel, hedges, fallbacks, zoo,
-                     profiles, regimes, regime_names, degraded,
-                     device_index, device_ids, t_inputs,
-                     arrivals) -> SimResult:
-    """Metrics + SimResult from a finished run — shared verbatim by
-    the python event loop and the scan engine."""
     viol = lat > cfg.t_sla
     prof_acc = np.array([p.accuracy for p in profiles])
     acc = prof_acc[np.maximum(sel, 0)]
-    if plan.od_accuracy is not None:
-        acc = np.where(sel < 0, plan.od_accuracy, acc)
+    if od_accuracy is not None:
+        acc = np.where(sel < 0, od_accuracy, acc)
     return SimResult(
         attainment=float(1.0 - viol.mean()),
         accuracy=float(acc.mean()),

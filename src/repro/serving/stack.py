@@ -50,13 +50,7 @@ class BlockNormals:
     scalar calls — so refilling from ``standard_normal(block)`` and
     affine-transforming per draw is bit-for-bit the scalar sequence
     while paying the generator call overhead once per `block` draws
-    (pinned by tests/test_cluster_engine.py).
-
-    `take(n)` hands the next n standard normals out as an array —
-    the scan cluster engine (serving/cluster_engine.py) pre-draws each
-    replica's whole stream from a deepcopy of this object, then calls
-    `take` on the live one to advance it by exactly the count the scan
-    consumed, so python and scan paths leave identical RNG state.
+    (pinned by tests/test_stack.py).
     """
 
     def __init__(self, seed, *, block: int = 256):
@@ -73,21 +67,6 @@ class BlockNormals:
         z = self._z[self._i]
         self._i += 1
         return float(loc + scale * z)
-
-    def take(self, n: int) -> np.ndarray:
-        """Consume the next `n` standard normals, leaving the state
-        exactly where n scalar `normal` calls would."""
-        out = np.empty(int(n), np.float64)
-        filled = 0
-        while filled < len(out):
-            if self._i >= len(self._z):
-                self._z = self.gen.standard_normal(self.block)
-                self._i = 0
-            k = min(len(self._z) - self._i, len(out) - filled)
-            out[filled:filled + k] = self._z[self._i:self._i + k]
-            self._i += k
-            filled += k
-        return out
 
 
 @dataclass

@@ -13,11 +13,6 @@ Typical entry-point usage::
     from repro.utils.config import configure
     configure(platform="cpu", x64=False, host_devices=8)
     import jax  # safe either way; jax must not be *initialised* yet
-
-Tests opt in via the ``REPRO_HOST_DEVICES`` env var (see
-``tests/conftest.py``): CI runs the engine suite once with
-``REPRO_HOST_DEVICES=4`` so the shard_map path is exercised on plain
-CPU runners.
 """
 
 from __future__ import annotations
@@ -75,9 +70,9 @@ def configure(platform: Optional[str] = None,
         process cannot silently fall back to a different backend.
     x64:
         Flip the *global* default float width.  Prefer the scoped
-        ``jax.enable_x64(True)`` context inside library code
-        (the scan engine does exactly that); the global switch is for
-        benchmark / CLI entry points that own the whole process.
+        ``jax.enable_x64(True)`` context inside library code; the
+        global switch is for benchmark / CLI entry points that own the
+        whole process.
     host_devices:
         Present ``N`` fake host devices on CPU via
         ``--xla_force_host_platform_device_count=N`` so shard_map /

@@ -1,17 +1,20 @@
 """Device-fleet layer tests (serving/fleet.py + simulator integration):
 per-device trace determinism, EstimatorBank isolation / lag semantics,
-outage-aware hedging firing exactly once per request, and on-device
-fallback accounting."""
+outage-aware hedging firing exactly once per request, on-device
+fallback accounting, and integer-keyed `ArrayFleet` populations."""
 
 import numpy as np
 import pytest
 
 from repro.configs.paper_zoo import paper_profiles
 from repro.core.selection import on_device_fallback_decision
-from repro.serving.fleet import (DeviceProfile, EstimatorBank, FleetMixture,
-                                 device_tier_profile, make_fleet)
+from repro.serving.cluster import Cluster, make_tenant_workload
+from repro.serving.fleet import (ArrayFleet, DeviceProfile, EstimatorBank,
+                                 FleetMixture, device_tier_profile,
+                                 make_fleet)
 from repro.serving.network import MIN_T_INPUT_MS, EWMAEstimator
 from repro.serving.simulator import SimConfig, simulate
+from repro.serving.stack import SimReplicaStack
 
 
 def _two_device_fleet(net_b="lte"):
@@ -260,3 +263,58 @@ def test_hedge_knob_validation():
         simulate(paper_profiles(), SimConfig(t_sla=300.0, n_requests=10,
                                              hedge="outage",
                                              hedge_at_p95=True))
+
+
+def test_estimator_bank_parses_spec_once(monkeypatch):
+    """Regression: the bank must parse its spec string exactly once and
+    stamp per-device estimators from the parsed factory — re-parsing on
+    every cold device is an O(fleet) cost."""
+    import repro.serving.fleet as fleet_mod
+    calls = []
+    real = fleet_mod.estimator_factory
+
+    def counting(spec, **kw):
+        calls.append(spec)
+        return real(spec, **kw)
+
+    monkeypatch.setattr(fleet_mod, "estimator_factory", counting)
+    bank = EstimatorBank("ewma:0.3", default_prior=50.0)
+    for key in range(64):
+        bank.observe(key, 10.0 + key)
+        bank.estimate(key)
+    assert calls == ["ewma:0.3"]
+    assert len(bank.keys()) == 64
+
+
+# -- ArrayFleet -------------------------------------------------------------
+
+def test_array_fleet_protocol():
+    fl = make_fleet("array:40:3")
+    assert make_fleet("array:40:3") is fl          # cached per spec
+    assert np.array_equal(fl.prior_array(),
+                          ArrayFleet(40, seed=3).prior_array())
+    assert fl.priors() == dict(enumerate(fl.prior_array()))
+    tr = fl.sample_trace(np.random.default_rng(0), 200)
+    assert tr.device_ids is None
+    assert 0 <= tr.device_index.min() and tr.device_index.max() < 40
+    assert (tr.t_input >= MIN_T_INPUT_MS).all()
+    with pytest.raises(ValueError):
+        make_fleet("array:40:3:9")
+
+
+def test_array_fleet_serves_through_simulate_and_cluster():
+    """Integer-keyed devices run the per-device estimator banks and
+    controller of `simulate()`, and the tenant columns of `Cluster`."""
+    cfg = SimConfig(t_sla=350.0, n_requests=300, seed=5,
+                    fleet=ArrayFleet(30, seed=2), policy="greedy_nw",
+                    controller="reactive", estimator_lag=1)
+    a = simulate(paper_profiles(), cfg)
+    b = simulate(paper_profiles(), cfg)
+    assert np.array_equal(a.selections, b.selections)
+    assert np.array_equal(a.latencies, b.latencies)
+    assert a.device_ids is None and a.device_index.max() < 30
+    mix = [dict(tenant="t", sla_class="gold", fleet="array:30:2")]
+    reqs = make_tenant_workload(mix, n_requests=120, rate_hz=20.0, seed=1)
+    assert {r.device_id.split("/")[0] for r in reqs} == {"t"}
+    cl = Cluster([SimReplicaStack(paper_profiles(), seed=4)], mix)
+    assert len(cl.run(reqs).records) == 120

@@ -331,73 +331,6 @@ def test_flash_valid_from_matches_naive(seed, b, t, window, edge):
                                atol=2e-5, rtol=2e-5)
 
 
-# -- scan engine vs python engine (from test_engine.py) --------------------
-
-@settings(max_examples=20, deadline=None)
-@given(
-    seed=st.integers(0, 2**31 - 1),
-    n_devices=st.integers(1, 6),
-    estimator=st.sampled_from(["observed", "ewma:0.4", "pctl:90",
-                               "pctl:50"]),
-    lag=st.integers(0, 2),
-    controller=st.booleans(),
-)
-def test_scan_engine_matches_python_engine(seed, n_devices, estimator,
-                                           lag, controller):
-    """Arbitrary small fleet workloads: the jit lax.scan column program
-    and the python reference loop make identical decisions (DESIGN.md
-    §13)."""
-    from repro.configs.paper_zoo import paper_profiles
-    from repro.serving.fleet import ArrayFleet
-    from repro.serving.simulator import SimConfig, simulate
-
-    if estimator == "observed" and not controller:
-        lag = 0     # EstimatorBank rejects "observed" under a lag
-    kw = ({"controller": "reactive", "estimator_lag": lag}
-          if controller else
-          {"t_estimator": estimator, "estimator_lag": lag})
-    out = {}
-    for engine in ("python", "scan"):
-        cfg = SimConfig(t_sla=350.0, n_requests=48, seed=seed,
-                        fleet=ArrayFleet(n_devices, seed=seed),
-                        policy="greedy_nw", engine=engine, **kw)
-        out[engine] = simulate(paper_profiles(), cfg)
-    a, b = out["python"], out["scan"]
-    assert list(a.selections) == list(b.selections)
-    np.testing.assert_allclose(np.asarray(a.latencies),
-                               np.asarray(b.latencies), rtol=1e-9)
-    ea = a.switch_events or []
-    eb = b.switch_events or []
-    assert [(e["request"], e["device"], e["from"], e["to"])
-            for e in ea] == [(e["request"], e["device"], e["from"],
-                              e["to"]) for e in eb]
-
-
-# -- scan cluster engine vs python Cluster (test_cluster_engine.py) --------
-
-@settings(max_examples=15, deadline=None)
-@given(
-    seed=st.integers(0, 2**31 - 1),
-    n=st.integers(20, 120),
-    rate=st.floats(5.0, 300.0, allow_nan=False, allow_infinity=False),
-    mix=st.sampled_from(["consumer_burst", "enterprise_degraded"]),
-    hedge=st.booleans(),
-    budget=st.booleans(),
-    controller=st.booleans(),
-)
-def test_cluster_scan_matches_python(seed, n, rate, mix, hedge, budget,
-                                     controller):
-    """Arbitrary small multi-tenant workloads: the jit lax.scan cluster
-    program and the python Cluster loop emit identical event logs,
-    metrics rows, and end-state (DESIGN.md §17)."""
-    from test_cluster_engine import _assert_bitwise, _pair
-
-    cp, cs = _pair(mix, n=n, rate=rate, seed=seed, hedge=hedge,
-                   budget=int(250e6) if budget else None,
-                   controller="reactive" if controller else None)
-    _assert_bitwise(cp, cs)
-
-
 # -- continuous batcher slot lifecycle (from test_serving.py) --------------
 
 @settings(max_examples=60, deadline=None)

@@ -56,20 +56,6 @@ def test_simulator_numbers_pinned(network, policy):
     assert r.mean_latency == pytest.approx(lat, abs=1e-9)
 
 
-@pytest.mark.parametrize("network,policy", sorted(GOLDEN),
-                         ids=lambda v: str(v))
-def test_scan_engine_reproduces_goldens(network, policy):
-    """The vectorized `engine="scan"` program (DESIGN.md §13) must land
-    on the same pinned numbers as the python reference loop."""
-    att, acc, lat = GOLDEN[(network, policy)]
-    r = simulate(paper_profiles(), SimConfig(
-        t_sla=SLA_MS, n_requests=N_REQUESTS, network=network,
-        policy=policy, seed=SEED, engine="scan"))
-    assert r.attainment == pytest.approx(att, abs=1e-12)
-    assert r.accuracy == pytest.approx(acc, abs=1e-12)
-    assert r.mean_latency == pytest.approx(lat, abs=1e-9)
-
-
 def test_fleet_none_is_the_golden_path():
     """`fleet=None` (the default) plus the new hedging/fleet knobs at
     their defaults must be byte-identical to the pinned pre-fleet

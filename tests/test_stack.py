@@ -5,7 +5,8 @@ One behavioural contract run against every implementation —
 `SimReplicaStack` (simulated replica), and `Cluster` (the composite):
 protocol shape, submit -> metrics round trip, tenant tagging, and the
 observe_outcome feedback path. Plus the deprecation pins for the
-pre-unification metrics aliases and `Router.enqueue`.
+pre-unification metrics aliases and `Router.enqueue`, and the
+`BlockNormals` pins (blocked draws are the scalar gaussian stream).
 """
 
 import warnings
@@ -26,8 +27,8 @@ from repro.serving.loop import ServingLoop
 from repro.serving.metrics import ServingMetrics
 from repro.serving.router import Router
 from repro.serving.server import CNNSelectServer, ServedModel
-from repro.serving.stack import (ServingStack, SimReplicaStack,
-                                 StackOutcome)
+from repro.serving.stack import (BlockNormals, ServingStack,
+                                 SimReplicaStack, StackOutcome)
 
 MODELS = ["mobilenetv1_025", "mobilenetv1_10"]
 
@@ -150,3 +151,29 @@ def test_router_enqueue_warns():
         r.enqueue(_req(0), MODELS[0])
     # Deprecated path still admits: the request reached the queue.
     assert len(r.queues[MODELS[0]]) == 1
+
+
+# -- BlockNormals (SimReplicaStack's gaussian stream) -----------------------
+
+def test_blocknormals_matches_scalar_stream():
+    ref = np.random.default_rng(123)
+    bn = BlockNormals(np.random.default_rng(123), block=7)
+    locs = np.random.default_rng(1).uniform(-50, 50, 300)
+    scales = np.random.default_rng(2).uniform(0.1, 20, 300)
+    for loc, scale in zip(locs, scales):
+        assert bn.normal(loc, scale) == ref.normal(loc, scale)
+
+
+def test_blocknormals_refills_across_blocks_like_scalars():
+    """Block refills consume the generator exactly like scalar draws:
+    after whole blocks the two generators hold the same state, and the
+    draws either side of each boundary match bit for bit."""
+    ref = np.random.default_rng(9)
+    bn = BlockNormals(np.random.default_rng(9), block=5)
+    got = [bn.normal(), *[bn.normal() for _ in range(13)],
+           bn.normal(2.0, 3.0), *[bn.normal() for _ in range(5)]]
+    want = [ref.normal(), *[ref.normal() for _ in range(13)],
+            ref.normal(2.0, 3.0), *[ref.normal() for _ in range(5)]]
+    assert got == want
+    assert bn.gen.bit_generator.state == ref.bit_generator.state
+    assert bn.normal(-1.0, 0.5) == ref.normal(-1.0, 0.5)
