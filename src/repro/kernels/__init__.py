@@ -1,7 +1,9 @@
 """Pallas TPU kernels for the serving hot spots.
 
 - flash_attention: prefill attention (GQA + sliding window + logit
-  softcap + causal), online-softmax over KV blocks in VMEM.
+  softcap + causal), online-softmax over KV blocks in VMEM — bf16
+  operands on the MXU with f32 accumulation, several heads a step, and
+  (q block, k block) pairs of at most 128 skipped by one gate (`runs`).
 - decode_attention: flash-decode over a (possibly ring-buffer) KV cache.
 - int8_matmul: per-channel-scaled int8-weight matmul (the TPU adaptation
   of the paper's 8-bit post-training quantization study — weight tiles

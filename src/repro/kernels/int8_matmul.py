@@ -42,7 +42,7 @@ from jax.experimental.pallas import tpu as pltpu
 SCOPED_VMEM = 16 * 2**20     # Mosaic's default scoped VMEM limit
 
 
-def _tile(d: int, cap: int, align: int) -> int:
+def tile(d: int, cap: int, align: int) -> int:
     """d itself if it fits under cap; else the largest multiple of align
     under cap that divides d; else the fewest blocks of at most cap,
     rounded up to align, so that d pads by less than one block."""
@@ -58,7 +58,7 @@ def _tile(d: int, cap: int, align: int) -> int:
 def tiles(M: int, K: int, N: int, dtype) -> tuple[int, int, int]:
     """(bm, bn, bk) for x (M, K) of `dtype` against Wq (K, N)."""
     sublanes = 32 // jnp.dtype(dtype).itemsize
-    return _tile(M, 1024, sublanes), _tile(N, 1024, 128), _tile(K, 2048, 128)
+    return tile(M, 1024, sublanes), tile(N, 1024, 128), tile(K, 2048, 128)
 
 
 def vmem_bytes(bm: int, bn: int, bk: int, n_k: int, dtype) -> int:

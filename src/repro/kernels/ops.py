@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention as _flash
+from repro.kernels.flash_attention import tiles as flash_tiles
 from repro.kernels.decode_attention import decode_attention as _decode
 from repro.kernels.int8_matmul import int8_matmul as _int8mm
 from repro.kernels.int8_matmul import tiles as int8_tiles
@@ -25,15 +26,17 @@ def _interpret() -> bool:
 @functools.partial(jax.jit, static_argnames=("window", "softcap", "scale",
                                              "block_q", "block_k"))
 def flash_attention_btHd(q, k, v, valid_from=None, *, window=0, softcap=0.0,
-                         scale=None, block_q=512, block_k=512):
+                         scale=None, block_q=None, block_k=None):
     """Model-layout wrapper: q (B,T,H,hd), k/v (B,S,KV,hd) — transposes to
     the kernel's (B,H,T,hd) layout and pads T/S to block multiples.
     valid_from: optional (B,) first attendable key index (0-based, same
-    axis as the kernel's implicit positions)."""
+    axis as the kernel's implicit positions). Blocks not given come from
+    the kernel's tile rule for this shape and dtype."""
     B, T, H, hd = q.shape
-    S = k.shape[1]
-    bq = min(block_q, max(T, 1))
-    bk = min(block_k, max(S, 1))
+    S, KV = k.shape[1], k.shape[2]
+    _, tq, tk = flash_tiles(B, H, KV, T, S, hd, q.dtype)
+    bq = min(block_q or tq, max(T, 1))
+    bk = min(block_k or tk, max(S, 1))
     pad_q = (-T) % bq
     pad_k = (-S) % bk
     qt = jnp.moveaxis(q, 2, 1)

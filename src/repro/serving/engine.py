@@ -10,6 +10,7 @@ regroups requests between steps and backfills freed slots via
 from __future__ import annotations
 
 import time
+from collections import Counter
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -18,6 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.kernels.flash_attention import blocks_run
+from repro.kernels.flash_attention import tiles as flash_tiles
 from repro.models import decode_step, forward, init_cache
 from repro.models.config import ATTN_KINDS, ModelConfig
 from repro.models.model import prefill
@@ -35,6 +38,11 @@ class EngineStats:
     # Copying the returned logits to the host, every kind of call; the
     # *_time_s above stop at the device result and leave it out.
     fetch_time_s: float = 0.0
+    # The flash prefill kernel's (row, q block, k block) triples, summed
+    # over the attention layers of every group prefill and backfill row:
+    # those its causal/window/valid_from gate runs, and all of them.
+    attn_blocks_run: int = 0
+    attn_blocks: int = 0
 
 
 class InferenceEngine:
@@ -59,6 +67,12 @@ class InferenceEngine:
         # max_seq (a windowed ring smaller than max_seq wraps slots).
         self._backfillable = self._maskable and not (
             "local" in kinds and cfg.window and cfg.window < max_seq)
+        # Window -> number of layers whose prefill runs the flash kernel
+        # with it (none unless attn_impl is "pallas"), for the counters.
+        layer_kinds = (cfg.pattern * cfg.n_groups_scan) + cfg.tail_kinds
+        self._flash_windows = Counter(
+            cfg.window if kind == "local" else 0 for kind in layer_kinds
+            if kind in ATTN_KINDS) if cfg.attn_impl == "pallas" else {}
 
         def _prefill(params, tokens, valid_from=None):
             return prefill(params, tokens, cfg, max_seq=max_seq,
@@ -150,12 +164,13 @@ class InferenceEngine:
         return out
 
     def _valid_from_for(self, tokens, lengths):
-        """(B,) first attendable absolute position per row, or None."""
+        """(B,) first attendable absolute position per row on the host,
+        or None."""
         B, T = tokens.shape
         if lengths is None:
             if not self._maskable:
                 return None
-            return jnp.zeros((B,), jnp.int32)
+            return np.zeros((B,), np.int32)
         if not self._maskable:
             raise NotImplementedError(
                 f"padded prompts need per-row masking, which recurrent "
@@ -163,7 +178,23 @@ class InferenceEngine:
         lengths = np.asarray(lengths, np.int64)
         if lengths.shape != (B,) or np.any(lengths < 1) or np.any(lengths > T):
             raise ValueError(f"lengths must be (B,) in [1, {T}]")
-        return jnp.asarray(T - lengths, jnp.int32)
+        return (T - lengths).astype(np.int32)
+
+    def _count_attn_blocks(self, valid_from, T: int):
+        """Add one prefill call's flash-kernel block triples to the
+        stats: valid_from (rows,) in the kernel's coordinates, over T
+        queries and T keys."""
+        if not self._flash_windows:
+            return
+        cfg = self.cfg
+        rows = len(valid_from)
+        _, bq, bk = flash_tiles(rows, cfg.q_heads_padded, cfg.n_kv_heads,
+                                T, T, cfg.head_dim, cfg.compute_dtype)
+        per_layer = rows * -(-T // bq) * -(-T // bk)
+        for window, layers in self._flash_windows.items():
+            self.stats.attn_blocks_run += layers * blocks_run(
+                valid_from, T, T, bq, bk, True, window)
+            self.stats.attn_blocks += layers * per_layer
 
     def run_prefill(self, tokens: np.ndarray, lengths=None):
         """tokens: (B, T) int32, left-padded; lengths: optional (B,) count
@@ -172,10 +203,14 @@ class InferenceEngine:
         later cache reads. Returns next-token logits; stores cache."""
         assert tokens.shape[0] == self.batch_size
         with TraceAnnotation("serve.launch"):
-            vf = self._valid_from_for(tokens, lengths)
+            vf_host = self._valid_from_for(tokens, lengths)
+            vf = None if vf_host is None else jnp.asarray(vf_host)
             t0 = time.perf_counter()
             logits, cache = self._prefill(self.params, jnp.asarray(tokens),
                                           vf)
+        self._count_attn_blocks(
+            np.zeros((len(tokens),), np.int32) if vf_host is None
+            else vf_host, tokens.shape[1])
         with TraceAnnotation("serve.sync"):
             logits.block_until_ready()
         self.stats.prefill_calls += 1
@@ -242,6 +277,7 @@ class InferenceEngine:
                 jnp.asarray([vf_row], jnp.int32))
             self.cache = self._merge(self.cache, rcache, jnp.int32(slot),
                                      jnp.int32(offset), T)
+        self._count_attn_blocks(np.asarray([T - length]), T)
         with TraceAnnotation("serve.sync"):
             logits.block_until_ready()
         self.stats.backfill_calls += 1

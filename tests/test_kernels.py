@@ -8,6 +8,9 @@ import pytest
 
 from repro.kernels import ops
 from repro.kernels import ref as R
+from repro.kernels.flash_attention import (blocks_run, flash_attention,
+                                           kv_block, runs, tiles,
+                                           vmem_bytes, VMEM_BUDGET)
 from repro.quant import quantize_int8
 
 SHAPES = [
@@ -17,6 +20,10 @@ SHAPES = [
     (1, 4, 1, 32, 8, 16, 0.0),     # MQA + window
     (2, 8, 2, 48, 32, 0, 50.0),    # softcap
     (1, 2, 2, 40, 64, 24, 30.0),   # window + softcap
+    (1, 32, 32, 32, 64, 0, 0.0),   # stablelm-1.6b's heads: 32 a step
+    (1, 32, 4, 32, 128, 0, 0.0),   # yi-9b's GQA: 8 q heads per kv head
+    (2, 8, 4, 48, 32, 20, 0.0),    # window, several heads a step
+    (1, 16, 8, 48, 64, 0, 30.0),   # softcap, several heads a step
 ]
 DTYPES = [jnp.float32, jnp.bfloat16]
 
@@ -147,6 +154,112 @@ def test_flash_attention_valid_from_offset_positions(rng):
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(jnp.moveaxis(ref, 1, 2)),
                                atol=1e-5, rtol=1e-5)
+
+
+# Head layouts a step can take: Hb from the tile rule (every head here),
+# and Hb below rep (one kv head read by a part of its q heads) or above
+# it (several kv heads a step). valid_from covers an empty slot (T - 1),
+# left-padded lengths on both sides of the 16-wide block edge, none, and
+# a row with no attendable key at all (T), which gives zeros.
+@pytest.mark.parametrize("Hq,KV,hd,block_h", [
+    (32, 32, 64, None),    # stablelm-1.6b: 32 heads a step
+    (32, 4, 128, None),    # yi-9b GQA: 4 kv heads a step
+    (32, 4, 128, 2),       # a quarter of a kv head's q heads a step
+    (16, 4, 32, 8),        # two kv heads a step
+], ids=["stablelm", "gqa", "gqa_hb2", "gqa_hb8"])
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_flash_attention_head_layouts_valid_from_vs_ref(Hq, KV, hd, block_h,
+                                                        dtype, rng):
+    B, T = 6, 48
+    q = jnp.asarray(rng.normal(size=(B, Hq, T, hd)), dtype)
+    k = jnp.asarray(rng.normal(size=(B, KV, T, hd)), dtype)
+    v = jnp.asarray(rng.normal(size=(B, KV, T, hd)), dtype)
+    vf = jnp.asarray([T - 1, 15, 16, 17, 0, T], jnp.int32)
+    out = flash_attention(q, k, v, vf, block_q=16, block_k=16,
+                          block_h=block_h, interpret=True)
+    ref = R.flash_attention_ref(q, k, v, valid_from=vf)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=tol,
+                               rtol=tol)
+    assert not np.asarray(out[5], np.float32).any()
+
+
+# -- the block gate and its host count ---------------------------------------
+
+def _attendable(qs, ks, vf, bq, bk, causal, window):
+    """Whether some (query, key) of the two blocks may attend, element
+    by element."""
+    qp = np.arange(qs, qs + bq)[:, None]
+    kp = np.arange(ks, ks + bk)[None, :]
+    ok = kp >= vf
+    if causal:
+        ok = ok & (kp <= qp)
+    if window:
+        ok = ok & (kp > qp - window)
+    return bool(ok.any())
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("window", [0, 5, 16, 40])
+@pytest.mark.parametrize("bq,bk", [(16, 16), (16, 32), (32, 16)])
+def test_blocks_run_counts_the_gate(causal, window, bq, bk):
+    """blocks_run equals the gate counted pair by pair; the gate skips
+    no pair holding an attendable key, and with square blocks and no
+    window it runs none without one; the k/v index map reads a pair's
+    own block wherever the gate runs it, and stays on blocks the gate
+    runs (or one block where none runs)."""
+    T = S = 96
+    n_q, n_k = T // bq, S // bk
+    vfs = np.arange(0, T + 1)
+    brute = 0
+    for vf in vfs:
+        for j in range(n_q):
+            ran = []
+            for t in range(n_k):
+                run = bool(runs(j * bq, t * bk, int(vf), bq, bk, causal,
+                                window))
+                brute += run
+                seen = _attendable(j * bq, t * bk, vf, bq, bk, causal,
+                                   window)
+                assert run or not seen
+                if bq == bk and not window:
+                    assert run == seen
+                if run:
+                    ran.append(t)
+            reads = {int(kv_block(t, j * bq, int(vf), bq, bk, n_k, causal,
+                                  window)) for t in range(n_k)}
+            assert reads == set(ran) or (not ran and len(reads) == 1)
+    assert blocks_run(vfs, T, S, bq, bk, causal, window) == brute
+
+
+def test_blocks_run_at_the_cell_shape():
+    """At T 256 in 128-wide blocks an empty slot (valid_from 255) runs 1
+    pair of 4, a prompt of at most 128 tokens 1, a longer one 3."""
+    assert tiles(16, 32, 32, 256, 256, 64, jnp.bfloat16)[1:] == (128, 128)
+    assert blocks_run([255], 256, 256, 128, 128) == 1
+    assert blocks_run([128], 256, 256, 128, 128) == 1
+    assert blocks_run([127], 256, 256, 128, 128) == 3
+    assert blocks_run([0, 255, 200], 256, 256, 128, 128) == 5
+
+
+@pytest.mark.parametrize("B,Hq,KV,T,hd", [
+    (16, 32, 32, 256, 64), (16, 32, 4, 256, 128), (4, 16, 8, 4096, 256),
+    (1, 56, 8, 200, 128), (4, 32, 32, 8, 64)])
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_flash_tiles_fit(B, Hq, KV, T, hd, dtype):
+    """The tile rule: blocks of at most 128 that divide the sequence (or
+    pad it by less than a block), aligned to the dtype's sublanes unless
+    whole; Hb a divisor of Hq compatible with rep, within the budget."""
+    Hb, bq, bk = tiles(B, Hq, KV, T, T, hd, dtype)
+    rep = Hq // KV
+    sub = 32 // jnp.dtype(dtype).itemsize
+    for blk in (bq, bk):
+        assert blk <= 128 and (blk == T or blk % sub == 0)
+        assert -(-T // blk) * blk - T < blk
+    assert Hq % Hb == 0 and (Hb % rep == 0 or rep % Hb == 0)
+    assert vmem_bytes(Hb, max(1, Hb // rep), bq, bk, hd, dtype) \
+        <= VMEM_BUDGET
 
 
 @pytest.mark.parametrize("ring", [False, True])

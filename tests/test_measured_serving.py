@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import reduced_config
+from repro.kernels.flash_attention import blocks_run
+from repro.kernels.flash_attention import tiles as flash_tiles
 from repro.kernels.ref import flash_attention_ref
 from repro.models import init_params
 from repro.models.layers import attention_naive
@@ -224,6 +226,40 @@ def test_backfill_matches_from_scratch_prefill(small):
     np.testing.assert_allclose(eng.run_decode(nxt[:, None]),
                                ref.run_decode(nxt[:, None]), atol=1e-4)
     assert eng.stats.backfill_calls == 1
+
+
+@pytest.mark.parametrize("arch", ["stablelm_1_6b", "gemma2_9b"])
+def test_attn_block_counters_follow_the_gate(arch):
+    """One group prefill and one backfill row at T 256 (128-wide blocks):
+    the engine's flash-kernel block counters are blocks_run of the
+    valid_from it builds, per attention layer with the layer's window;
+    an engine on another attention impl counts nothing."""
+    cfg = dataclasses.replace(reduced_config(arch), attn_impl="pallas")
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    B, T = 3, 256
+    lengths = np.array([T, 100, 1])
+    tokens = np.random.default_rng(3).integers(0, cfg.vocab, (B, T),
+                                               dtype=np.int32)
+    eng = InferenceEngine(cfg, params, batch_size=B, max_seq=2 * T)
+    eng.run_prefill(tokens, lengths=lengths)
+    _, bq, bk = flash_tiles(B, cfg.q_heads_padded, cfg.n_kv_heads, T, T,
+                            cfg.head_dim, cfg.compute_dtype)
+    assert (bq, bk) == (128, 128)
+    kinds = [cfg.pattern[i % len(cfg.pattern)] for i in range(cfg.n_layers)]
+    windows = [cfg.window if kind == "local" else 0 for kind in kinds]
+    vf = T - lengths
+    assert eng.stats.attn_blocks == len(windows) * B * 4
+    assert eng.stats.attn_blocks_run == sum(
+        blocks_run(vf, T, T, bq, bk, True, w) for w in windows)
+    if arch == "stablelm_1_6b":
+        assert eng.stats.attn_blocks_run == len(windows) * (3 + 1 + 1)
+        eng.prefill_row(tokens[0], 1, length=200)
+        assert eng.stats.attn_blocks == len(windows) * (B + 1) * 4
+        assert eng.stats.attn_blocks_run == len(windows) * (5 + 3)
+    other = InferenceEngine(dataclasses.replace(cfg, attn_impl="naive"),
+                            params, batch_size=B, max_seq=2 * T)
+    other.run_prefill(tokens, lengths=lengths)
+    assert other.stats.attn_blocks == other.stats.attn_blocks_run == 0
 
 
 def test_prefill_row_guards(small):

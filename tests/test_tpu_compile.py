@@ -63,6 +63,29 @@ def test_flash_attention_compiles_stablelm(one_chip, T, dtype):
     assert "tpu_custom_call" in txt
 
 
+# The wrapper with its own tiles at the benchmark cell's group prefill
+# (batch 16, T 256: 32 heads a step, 128-wide blocks) and at yi-9b's
+# GQA layout (32 q heads over 4 kv heads, hd 128), with the skipped
+# steps' k/v index maps read from valid_from: a tile that asks more
+# VMEM than Mosaic allows, or a misaligned fold of q heads into rows,
+# fails here.
+@pytest.mark.parametrize("Hq,KV,hd", [(32, 32, 64), (32, 4, 128)],
+                         ids=["stablelm", "yi_gqa"])
+def test_flash_attention_own_tiles_compile(one_chip, monkeypatch, Hq, KV,
+                                           hd):
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    B, T = 16, 256
+    txt = _compiled_text(
+        lambda q, k, v, vf: ops.flash_attention_btHd.__wrapped__(q, k, v,
+                                                                 vf),
+        one_chip, ((B, T, Hq, hd), jnp.bfloat16),
+        ((B, T, KV, hd), jnp.bfloat16), ((B, T, KV, hd), jnp.bfloat16),
+        ((B,), jnp.int32))
+    assert "tpu_custom_call" in txt
+    assert " pad(" not in txt
+
+
 # rep = Hq / KV: 1 for stablelm-1.6b (hd 64), 8 for yi-9b (32 over 4,
 # hd 128). A 512-slot linear cache, the serving engine's layout.
 @pytest.mark.parametrize("Hq,KV,hd", [(32, 32, 64), (32, 4, 128)],
